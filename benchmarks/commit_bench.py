@@ -85,7 +85,7 @@ def run(args):
     sizes = [int(s) for s in args.streams.split(",")]
     B = max(sizes)
     L, S, H, hd, Tpad = args.layers, args.smax, args.kv_heads, args.head_dim, args.tpad
-    cfg = types.SimpleNamespace(attention_impl=args.impl, kernel_interpret=True)
+    cfg = types.SimpleNamespace(attention_impl=args.impl)
     rng = np.random.default_rng(args.seed)
     print(f"pool: L={L} B={B} Smax={S} Hkv={H} hd={hd}  Tpad={Tpad}  impl={args.impl}")
     print(f"{'streams':>8} {'seq ms/step':>12} {'fused ms/step':>14} {'speedup':>8} "

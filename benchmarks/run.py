@@ -33,7 +33,7 @@ def micro_rows():
     from repro.core.otlp import OTLP_SOLVERS
     from repro.core.traversal import verify_traversal
     from repro.core.trees import attach_target, build_delayed_tree
-    from repro.kernels.ops import gqa_decode_attention, gqa_tree_attention
+    from repro.kernels.ops import gqa_decode_attention, gqa_tree_attention, interpret_mode
     from benchmarks.common import make_process
 
     rows = []
@@ -50,24 +50,26 @@ def micro_rows():
     us = _time(lambda: verify_traversal(tree, rng), n=100)
     rows.append(("verify_traversal", us, "K2,L1=2,L2=2"))
 
+    interp = interpret_mode()
+    route = "interpret" if interp else "mosaic"
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 4)
     qq = jax.random.normal(ks[0], (1, 8, 4, 128), jnp.float32)
     kk = jax.random.normal(ks[1], (1, 256, 2, 128), jnp.float32)
     vv = jax.random.normal(ks[2], (1, 256, 2, 128), jnp.float32)
     mm = jax.random.bernoulli(ks[3], 0.7, (1, 8, 256))
-    out = gqa_tree_attention(qq, kk, vv, mm, block_k=128, interpret=True)
+    out = gqa_tree_attention(qq, kk, vv, mm, block_k=128, interpret=interp)
     jax.block_until_ready(out)
     us = _time(lambda: jax.block_until_ready(
-        gqa_tree_attention(qq, kk, vv, mm, block_k=128, interpret=True)), n=5)
-    rows.append(("pallas_tree_attention_interpret", us, "T8,S256,H4"))
+        gqa_tree_attention(qq, kk, vv, mm, block_k=128, interpret=interp)), n=5)
+    rows.append((f"pallas_tree_attention_{route}", us, "T8,S256,H4"))
     q1 = jax.random.normal(ks[0], (1, 1, 4, 128), jnp.float32)
     ln = jnp.asarray([250], jnp.int32)
-    out = gqa_decode_attention(q1, kk, vv, ln, block_k=128, interpret=True)
+    out = gqa_decode_attention(q1, kk, vv, ln, block_k=128, interpret=interp)
     jax.block_until_ready(out)
     us = _time(lambda: jax.block_until_ready(
-        gqa_decode_attention(q1, kk, vv, ln, block_k=128, interpret=True)), n=5)
-    rows.append(("pallas_decode_attention_interpret", us, "S256,H4"))
+        gqa_decode_attention(q1, kk, vv, ln, block_k=128, interpret=interp)), n=5)
+    rows.append((f"pallas_decode_attention_{route}", us, "S256,H4"))
     return rows
 
 

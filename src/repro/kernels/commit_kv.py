@@ -25,9 +25,11 @@ Padding convention: masked entries carry src == dst (an identity copy of a
 slot no real entry writes), so ragged per-row path lengths need no masking
 inside the kernel.
 
-Layout: k, v (L, B, Smax, Hkv, hd); src, dst (B, P) int32.  The feature
-lanes are reshaped to (Hkv * hd,); real deployments have hd = 128 so the
-lane dim is MXU/VPU aligned.
+Layout: k, v (L, B, Smax, Hkv, hd); src, dst (B, P) int32.  One moved
+lane is a (1, 1, 1, Hkv, hd) block: its last two dims are the array's own,
+which is the form the TPU lowering accepts for any head count and head
+width (a flattened (1, 1, 1, Hkv * hd) block puts a size-1 block on the
+tiled slot axis and is refused).
 
 Paged pools reuse this kernel unchanged: logical slots are translated
 through the block table and the arena is committed as a single-row pool
@@ -49,7 +51,7 @@ def _commit_kv_kernel(src_ref, dst_ref, k_in, v_in, k_out, v_out):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def commit_kv(k, v, src, dst, *, interpret: bool = True):
+def commit_kv(k, v, src, dst, *, interpret: bool):
     """k[l, b, dst[b, j]] <- k[l, b, src[b, j]] (and likewise v), in place.
 
     k, v: (L, B, Smax, Hkv, hd); src, dst: (B, P) int32.  Requires the
@@ -63,29 +65,22 @@ def commit_kv(k, v, src, dst, *, interpret: bool = True):
     """
     L, B, S, H, hd = k.shape
     P = src.shape[1]
-    F = H * hd
-    kf = k.reshape(L, B, S, F)
-    vf = v.reshape(L, B, S, F)
+    lane = (1, 1, 1, H, hd)
+    read = lambda l, b, j, src, dst: (l, b, src[b, j], 0, 0)
+    write = lambda l, b, j, src, dst: (l, b, dst[b, j], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(L, B, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, F), lambda l, b, j, src, dst: (l, b, src[b, j], 0)),
-            pl.BlockSpec((1, 1, 1, F), lambda l, b, j, src, dst: (l, b, src[b, j], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, F), lambda l, b, j, src, dst: (l, b, dst[b, j], 0)),
-            pl.BlockSpec((1, 1, 1, F), lambda l, b, j, src, dst: (l, b, dst[b, j], 0)),
-        ],
+        in_specs=[pl.BlockSpec(lane, read), pl.BlockSpec(lane, read)],
+        out_specs=[pl.BlockSpec(lane, write), pl.BlockSpec(lane, write)],
     )
-    ko, vo = pl.pallas_call(
+    return pl.pallas_call(
         _commit_kv_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(kf.shape, kf.dtype),
-            jax.ShapeDtypeStruct(vf.shape, vf.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
-    )(src, dst, kf, vf)
-    return ko.reshape(k.shape), vo.reshape(v.shape)
+    )(src, dst, k, v)

@@ -74,7 +74,7 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q, k_arena, v_arena, tbl, lengths, *, window: int = 0,
-                           interpret: bool = False):
+                           interpret: bool):
     """Flash-decode over a paged KV pool: KV streams through the block table.
 
     q (BH, 8, D); k_arena, v_arena (NBLK, block, D); tbl (BH, max_blocks)
@@ -116,7 +116,7 @@ def paged_decode_attention(q, k_arena, v_arena, tbl, lengths, *, window: int = 0
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "window", "interpret"))
-def decode_attention(q, k, v, lengths, *, block_k: int = 1024, window: int = 0, interpret: bool = False):
+def decode_attention(q, k, v, lengths, *, block_k: int = 1024, window: int = 0, interpret: bool):
     """q (BH, 8, D) (query broadcast over 8 sublanes, row 0 real);
     k, v (BH, S, D); lengths (BH, 1) int32.  Returns (BH, 8, D)."""
     BH, R, D = q.shape

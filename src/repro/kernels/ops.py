@@ -1,13 +1,15 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handle GQA head-group broadcasting, padding to TPU tile boundaries, and the
-interpret-mode fallback (this container is CPU-only: interpret=True executes
-the kernel body in Python for correctness validation; on TPU the same call
-compiles to Mosaic).
+Handle GQA head-group broadcasting and padding to TPU tile boundaries.
+Every wrapper takes ``interpret`` as a required keyword: callers on the
+serving path pass :func:`interpret_mode`, the one place the flag is
+decided, so kernels compile to Mosaic on a TPU and run in the Pallas
+interpreter only on the CPU backend (tests, rehearsals).
 """
 from __future__ import annotations
 
 import functools
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,20 @@ from repro.kernels.tree_attention import (
 )
 
 
-def pool_commit_kv(k, v, src, dst, *, use_pallas: bool = False, interpret: bool = True):
+# (kernel, interpret) -> times its wrapper was traced into a program: the
+# record chip_smoke.py reads to show that no kernel was built interpreted
+# on the chip.  Tracing runs once per compiled shape, so this costs nothing
+# per step.
+KERNEL_TRACES: Counter = Counter()
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted: True only on the CPU backend.
+    On a TPU every kernel compiles to Mosaic; no other backend is served."""
+    return jax.default_backend() == "cpu"
+
+
+def pool_commit_kv(k, v, src, dst, *, use_pallas: bool, interpret: bool):
     """Ring-compaction commit over the per-stream KV pool.
 
     k, v (L, B, Smax, Hkv, hd); src, dst (B, P) int32 slot indices (padding
@@ -33,6 +48,7 @@ def pool_commit_kv(k, v, src, dst, *, use_pallas: bool = False, interpret: bool 
     if use_pallas:
         from repro.kernels.commit_kv import commit_kv
 
+        KERNEL_TRACES["commit_kv", interpret] += 1
         return commit_kv(k, v, src, dst, interpret=interpret)
     from repro.kernels.ref import commit_kv_ref
 
@@ -49,12 +65,13 @@ def _pad_to(x, mult, axis):
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def gqa_tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool = True):
+def gqa_tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool):
     """Engine-layout tree attention.
 
     q (B, T, H, D); k, v (B, S, Hkv, D); mask (B, T, S) or (1, T, S) bool.
     Returns (B, T, H, D).
     """
+    KERNEL_TRACES["tree_attention", interpret] += 1
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -95,13 +112,14 @@ def _fold_paged_arena(k_arena, v_arena, tbl, H):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gqa_paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool = True):
+def gqa_paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool):
     """Engine-layout tree attention over a paged KV pool.
 
     q (B, T, H, D); k_arena, v_arena (NBLK, block, Hkv, D); tbl
     (B, max_blocks) int32 (-1 = unmapped); mask (B, T, S) or (1, T, S) bool
     over logical slots, S = max_blocks*block (unmapped slots carry pos = -1
     upstream, so the mask is False there).  Returns (B, T, H, D)."""
+    KERNEL_TRACES["paged_tree_attention", interpret] += 1
     B, T, H, D = q.shape
     nb, block = tbl.shape[1], k_arena.shape[1]
     S = nb * block
@@ -117,7 +135,7 @@ def gqa_paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gqa_ragged_tree_attention(q, k_arena, v_arena, tbl, owner, mask, *,
-                              interpret: bool = True):
+                              interpret: bool):
     """Engine-layout RAGGED tree attention over a paged KV pool.
 
     q (N, H, D) — the flat node-major buffer of every active stream's tree
@@ -130,6 +148,7 @@ def gqa_ragged_tree_attention(q, k_arena, v_arena, tbl, owner, mask, *,
     their rows are garbage and sliced off) and hands the kernel one owner
     per 8-row Q tile; the engine's 8-aligned segment offsets guarantee
     tiles are owner-uniform for real nodes."""
+    KERNEL_TRACES["ragged_paged_tree_attention", interpret] += 1
     N, H, D = q.shape
     nb, block = tbl.shape[1], k_arena.shape[1]
     S = nb * block
@@ -146,11 +165,12 @@ def gqa_ragged_tree_attention(q, k_arena, v_arena, tbl, owner, mask, *,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def gqa_paged_decode_attention(q, k_arena, v_arena, tbl, lengths, *, window: int = 0,
-                               interpret: bool = True):
+                               interpret: bool):
     """Engine-layout flash-decode over a paged KV pool.
 
     q (B, 1, H, D); k_arena, v_arena (NBLK, block, Hkv, D); tbl
     (B, max_blocks) int32; lengths (B,) int32.  Returns (B, 1, H, D)."""
+    KERNEL_TRACES["paged_decode_attention", interpret] += 1
     B, _, H, D = q.shape
     qf = jnp.broadcast_to(q.transpose(0, 2, 1, 3), (B, H, 8, D)).reshape(B * H, 8, D)
     kf, vf, tbl_f = _fold_paged_arena(k_arena, v_arena, tbl, H)
@@ -160,12 +180,13 @@ def gqa_paged_decode_attention(q, k_arena, v_arena, tbl, lengths, *, window: int
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "window", "interpret"))
-def gqa_decode_attention(q, k, v, lengths, *, block_k: int = 1024, window: int = 0, interpret: bool = True):
+def gqa_decode_attention(q, k, v, lengths, *, block_k: int = 1024, window: int = 0, interpret: bool):
     """Engine-layout flash-decode.
 
     q (B, 1, H, D); k, v (B, S, Hkv, D); lengths (B,) int32.
     Returns (B, 1, H, D).
     """
+    KERNEL_TRACES["decode_attention", interpret] += 1
     B, _, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv

@@ -11,8 +11,9 @@ kernel (DeFT-style); the TPU-native formulation here:
   * keys/values stream HBM -> VMEM in ``block_k`` chunks along the grid's
     sequential minor axis (TPU grids execute in order, so cross-block
     accumulation needs no atomics — the GPU split-k reduction disappears);
-  * the boolean mask streams with the same blocking; MXU matmuls are
-    (T, D) x (D, block_k) with D = head_dim = 128 — hardware-aligned.
+  * the boolean mask streams with the same blocking, as (T, block_k)
+    tiles of a block-major view (``_block_major``); MXU matmuls are
+    (T, D) x (D, block_k) with D = head_dim.
 
 Layouts: q (BH, T, D);  k, v (BH, S, D);  mask (BH, T, S).  The ops.py
 wrapper folds batch x heads and broadcasts GQA groups.
@@ -33,9 +34,10 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _attn_tile_body(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref, j, nk):
-    """One K/V-block step of the online softmax; j is the sequential minor
-    grid axis (0-based), nk its extent."""
+def _attn_tile_body(q_ref, k_ref, v_ref, mask, o_ref, m_ref, l_ref, acc_ref, j, nk):
+    """One K/V-block step of the online softmax; ``mask`` is this block's
+    loaded (T, Bk) bool tile, j the sequential minor grid axis (0-based), nk
+    its extent."""
 
     @pl.when(j == 0)
     def _init():
@@ -46,7 +48,6 @@ def _attn_tile_body(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)  # (T, D)
     k = k_ref[0].astype(jnp.float32)  # (Bk, D)
     v = v_ref[0].astype(jnp.float32)  # (Bk, D)
-    mask = mask_ref[0]  # (T, Bk) bool
 
     d = q.shape[-1]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) / (d**0.5)  # (T, Bk)
@@ -69,7 +70,7 @@ def _attn_tile_body(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def _tree_attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref):
-    _attn_tile_body(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
+    _attn_tile_body(q_ref, k_ref, v_ref, mask_ref[0, 0], o_ref, m_ref, l_ref, acc_ref,
                     pl.program_id(1), pl.num_programs(1))
 
 
@@ -81,12 +82,23 @@ def _paged_tree_attn_kernel(tbl_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref
 def _ragged_tree_attn_kernel(owners_ref, tbl_ref, q_ref, k_ref, v_ref, mask_ref,
                              o_ref, m_ref, l_ref, acc_ref):
     del owners_ref, tbl_ref  # consumed by the K/V index maps
-    _attn_tile_body(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
+    _attn_tile_body(q_ref, k_ref, v_ref, mask_ref[0, 0], o_ref, m_ref, l_ref, acc_ref,
                     pl.program_id(2), pl.num_programs(2))
 
 
+def _block_major(mask, block):
+    """(R, T, nb*block) -> (R, nb, T, block): the logical block index moves
+    to a leading axis, so a mask tile's last two dims are (T, block), the
+    whole trailing extent.  The TPU lowering takes a tile whose minor dim
+    is neither a multiple of 128 nor the full array only in this form: the
+    flat (1, T, block) tile is refused for block < 128 (e.g. the serving
+    default of 64).  Every tree-attention kernel takes its mask this way."""
+    R, T, S = mask.shape
+    return mask.reshape(R, T, S // block, block).transpose(0, 2, 1, 3)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool = False):
+def paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool):
     """Block-table tree attention: KV streams straight from the paged arena.
 
     q (BH, T, D); k_arena, v_arena (NBLK, block, D) — the folded per-head
@@ -113,7 +125,7 @@ def paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool = Fa
             pl.BlockSpec((1, T, D), lambda i, j, tbl: (i, 0, 0)),
             pl.BlockSpec((1, block, D), lambda i, j, tbl: (tbl[i, j], 0, 0)),
             pl.BlockSpec((1, block, D), lambda i, j, tbl: (tbl[i, j], 0, 0)),
-            pl.BlockSpec((1, T, block), lambda i, j, tbl: (i, 0, j)),
+            pl.BlockSpec((1, 1, T, block), lambda i, j, tbl: (i, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, D), lambda i, j, tbl: (i, 0, 0)),
         scratch_shapes=[
@@ -127,12 +139,12 @@ def paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool = Fa
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=interpret,
-    )(tbl, q, k_arena, v_arena, mask)
+    )(tbl, q, k_arena, v_arena, _block_major(mask, block))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ragged_paged_tree_attention(q, k_arena, v_arena, tbl, owners, mask, *,
-                                interpret: bool = False):
+                                interpret: bool):
     """Ragged node-major tree attention over a paged arena.
 
     The Q axis is not a per-stream tree block but the FLAT ragged node
@@ -168,7 +180,7 @@ def ragged_paged_tree_attention(q, k_arena, v_arena, tbl, owners, mask, *,
                          lambda h, t, j, owners, tbl: (tbl[owners[t] * H + h, j], 0, 0)),
             pl.BlockSpec((1, block, D),
                          lambda h, t, j, owners, tbl: (tbl[owners[t] * H + h, j], 0, 0)),
-            pl.BlockSpec((1, 8, block), lambda h, t, j, owners, tbl: (t, 0, j)),
+            pl.BlockSpec((1, 1, 8, block), lambda h, t, j, owners, tbl: (t, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 8, D), lambda h, t, j, owners, tbl: (h, t, 0)),
         scratch_shapes=[
@@ -182,15 +194,16 @@ def ragged_paged_tree_attention(q, k_arena, v_arena, tbl, owners, mask, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, Np, D), q.dtype),
         interpret=interpret,
-    )(owners, tbl, q, k_arena, v_arena, mask)
+    )(owners, tbl, q, k_arena, v_arena, _block_major(mask, block))
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool = False):
+def tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool):
     """q (BH, T, D); k, v (BH, S, D); mask (BH, T, S) -> (BH, T, D).
 
     S must be a multiple of block_k (caller pads; padded slots masked False).
-    T should be a multiple of 8 and D of 128 for TPU tiling.
+    T and block_k should be multiples of 8 for TPU tiling; D is any head
+    width (64 and 128 compile for a v5e).
     """
     BH, T, D = q.shape
     S = k.shape[1]
@@ -204,7 +217,7 @@ def tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool = False
             pl.BlockSpec((1, T, D), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, T, block_k), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, 1, T, block_k), lambda i, j: (i, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, D), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
@@ -214,4 +227,4 @@ def tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool = False
             pltpu.VMEM((T, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, mask)
+    )(q, k, v, _block_major(mask, block_k))

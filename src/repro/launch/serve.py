@@ -18,17 +18,26 @@ tail overlaps the next step's dispatched device work, token-identically);
 (shard-local slots, block arenas, admission queues; pool arrays committed
 to the mesh data axis) under a least-loaded scheduler — token-identical to
 the unsharded pool for the same arrival order.
+
+``--attention-impl pallas`` routes attention through the Pallas kernels
+(compiled to Mosaic on a TPU, interpreted on the CPU backend).  The report
+names the device it ran on; JAX's persistent compilation cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache`` at the root of
+the checkout (``setup_compile_cache``).
 """
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.core.verify import verifier_names
+from repro.launch.mesh import shard_meshes
 from repro.models.transformer import init_params
 from repro.serving.batch_engine import (
     BatchedSpeculativeEngine,
@@ -64,6 +73,24 @@ def make_draft_cfg(cfg):
     if cfg.arch_type == "encdec":
         kw["n_enc_layers"] = max(cfg.n_enc_layers // 4, 1)
     return cfg.replace(**kw)
+
+
+def setup_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory and
+    return it: ``JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache``
+    at the root of the checkout (git-ignored).  A fixed path matters: the
+    directory is part of every entry's key, so a path holding a temporary
+    name, a process id or a time would never hit."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_label() -> str:
+    """The device the run is on, as JAX reports it: platform, kind, count."""
+    devs = jax.devices()
+    return f"{devs[0].platform}:{devs[0].device_kind} x{len(devs)}"
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -106,6 +133,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "verify/retire tail with the next step's dispatched "
                          "device work (token-identical; --no-pipeline "
                          "restores strictly sequential steps)")
+    ap.add_argument("--attention-impl", default="xla", choices=["xla", "pallas"],
+                    help="attention route of target and draft: XLA einsums, "
+                         "or the Pallas kernels (kernels/)")
     ap.add_argument("--ragged", default=True,
                     action=argparse.BooleanOptionalAction,
                     help="ragged node-major tree batching: dispatch the tree "
@@ -116,35 +146,46 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None):
-    args = build_parser().parse_args(argv)
-
+def build_models(args):
+    """Target and draft configs plus seeded random params for the CLI args:
+    the published config (or its ``--smoke`` preset) and the proportional
+    draft of ``make_draft_cfg``, both on the ``--attention-impl`` route."""
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    cfg = cfg.replace(attention_impl=args.attention_impl)
     dcfg = make_draft_cfg(cfg)
-    key = jax.random.PRNGKey(args.seed)
-    tp = init_params(cfg, key)
+    tp = init_params(cfg, jax.random.PRNGKey(args.seed))
     dp = init_params(dcfg, jax.random.PRNGKey(args.seed + 1))
+    return cfg, tp, dcfg, dp
 
+
+def build_engine(args, cfg, tp, dcfg, dp, devices=None):
+    """The engine the CLI args select: the continuous-batching pool
+    (``--streams N``; split into shard engines by ``--data-shards``, placed
+    round-robin on ``devices``, by default every local device) or the
+    single-stream engine (``--streams 0``), seeded by ``--seed``."""
     ecfg = EngineConfig(verifier=args.verifier, K=args.K, L1=args.L1, L2=args.L2,
                         max_cache=1024, seed=args.seed)
     sampling = SamplingParams(args.temperature, args.top_p)
+    if not args.streams:
+        return SpeculativeEngine(cfg, tp, dcfg, dp, ecfg, sampling)
+    kw = dict(n_slots=args.streams, paged=not args.ring,
+              block_size=args.block_size, pool_blocks=args.pool_blocks or None,
+              pipeline=args.pipeline, ragged=args.ragged)
+    if args.data_shards > 1:
+        return ShardedBatchedSpeculativeEngine(
+            cfg, tp, dcfg, dp, ecfg, sampling, data_shards=args.data_shards,
+            meshes=shard_meshes(args.data_shards, devices=devices), **kw)
+    return BatchedSpeculativeEngine(cfg, tp, dcfg, dp, ecfg, sampling, **kw)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    setup_compile_cache()
+    cfg, tp, dcfg, dp = build_models(args)
+    eng = build_engine(args, cfg, tp, dcfg, dp)
     rng = np.random.default_rng(args.seed)
 
     if args.streams:
-        if args.data_shards > 1:
-            eng = ShardedBatchedSpeculativeEngine(
-                cfg, tp, dcfg, dp, ecfg, sampling, n_slots=args.streams,
-                data_shards=args.data_shards, paged=not args.ring,
-                block_size=args.block_size,
-                pool_blocks=args.pool_blocks or None, pipeline=args.pipeline,
-                ragged=args.ragged)
-        else:
-            eng = BatchedSpeculativeEngine(cfg, tp, dcfg, dp, ecfg, sampling,
-                                           n_slots=args.streams, paged=not args.ring,
-                                           block_size=args.block_size,
-                                           pool_blocks=args.pool_blocks or None,
-                                           pipeline=args.pipeline,
-                                           ragged=args.ragged)
         t0 = time.time()
         rids = [
             eng.submit(rng.integers(0, cfg.vocab, size=8).tolist(),
@@ -182,11 +223,11 @@ def main(argv=None):
             f"target_calls={c['target_calls']} draft_tokens={c['draft_tokens']} "
             f"evicted={c['evicted']} pool={pool} stepping={stepping} "
             f"wall={dt:.1f}s "
-            f"tokens/s(cpu)={sum(len(o['tokens']) for o in outs.values()) / dt:.2f}"
+            f"tokens/s={sum(len(o['tokens']) for o in outs.values()) / dt:.2f} "
+            f"device={device_label()}"
         )
         return
 
-    eng = SpeculativeEngine(cfg, tp, dcfg, dp, ecfg, sampling)
     t0 = time.time()
     kw = {}
     if cfg.arch_type == "encdec":
@@ -203,7 +244,7 @@ def main(argv=None):
         f"\nverifier={args.verifier} ({args.K},{args.L1},{args.L2}) "
         f"block_efficiency={be:.3f} target_calls={c['target_calls']} "
         f"draft_tokens={c['draft_tokens']} wall={dt:.1f}s "
-        f"tokens/s(cpu)={args.requests * args.max_new / dt:.2f}"
+        f"tokens/s={args.requests * args.max_new / dt:.2f} device={device_label()}"
     )
 
 

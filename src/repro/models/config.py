@@ -34,10 +34,10 @@ class ModelConfig:
     attention: str = "full"
     window: int = 8192
     # attention implementation: "xla" (jnp einsum; SPMD-friendly, default) or
-    # "pallas" (the kernels/ masked-flash kernel; head_dim must be 128 on
-    # real TPUs; interpret=True executes on CPU for validation)
+    # "pallas" (the kernels/ masked-flash kernels; they compile for a TPU v5e
+    # at head_dim 64 and 128, and run interpreted only on the CPU backend —
+    # kernels/ops.interpret_mode)
     attention_impl: str = "xla"
-    kernel_interpret: bool = True
 
     # MoE — inference routing is dropless (exactness; see models/moe.py);
     # capacity_factor bounds the training dispatch buffers only

@@ -16,6 +16,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import (
+    gqa_paged_tree_attention,
+    gqa_ragged_tree_attention,
+    gqa_tree_attention,
+    interpret_mode,
+)
 from repro.models.cache import (
     append_layer_kv,
     attn_mask_from_pos,
@@ -188,11 +194,9 @@ def _self_attention(p, cfg, x, positions, mask, layer_cache, window, ragged=None
         new_kv = (kc, vc)
         N = x.shape[1]
         if cfg.attention_impl == "pallas" and page_tbl is not None:
-            from repro.kernels.ops import gqa_ragged_tree_attention
-
             att = gqa_ragged_tree_attention(
                 q[0], kc, vc, page_tbl, owner, mask[:, 0, 0],
-                interpret=cfg.kernel_interpret,
+                interpret=interpret_mode(),
             )
         else:
             # XLA path: per-node gather of the owner row's logical view
@@ -216,14 +220,10 @@ def _self_attention(p, cfg, x, positions, mask, layer_cache, window, ragged=None
     if cfg.attention_impl == "pallas" and mask is not None:
         m3 = mask[:, 0] if mask.ndim == 4 else mask
         if page_tbl is not None:
-            from repro.kernels.ops import gqa_paged_tree_attention
-
             att = gqa_paged_tree_attention(q, kc, vc, page_tbl, m3,
-                                           interpret=cfg.kernel_interpret)
+                                           interpret=interpret_mode())
         else:
-            from repro.kernels.ops import gqa_tree_attention
-
-            att = gqa_tree_attention(q, k, v, m3, interpret=cfg.kernel_interpret)
+            att = gqa_tree_attention(q, k, v, m3, interpret=interpret_mode())
     else:
         att = gqa_attend(q, k, v, mask)
     return x + att.reshape(B, T, -1) @ p["attn"]["wo"], new_kv

@@ -44,7 +44,13 @@ independent ``SpeculativeEngine`` run per stream.  This leans on three facts:
     (idle slots) or extra query tokens (masked via ``lens`` / the ancestor
     mask) contributes exact zeros to softmax sums, so logits are bit-equal
     to the unpadded single-stream call (verified: dense/ssm/hybrid logits
-    are invariant to batch size on the XLA CPU/TPU paths);
+    are invariant to batch size on the XLA CPU path.  Not so on the TPU:
+    granite-3-2b at full width in bf16 on a v5e gave single-stream tokens
+    that differ from the batched pool's.  The two engines run programs of
+    different shapes, and XLA may tile their bf16 reductions differently
+    there; the cause is not isolated yet.  On the chip the contract is
+    checked between pipelined and synchronous pool steps, and between the
+    sharded and the unsharded pool, not against the single-stream engine);
   * MoE routing is dropless (models/moe.py), so expert outputs do not
     depend on batch co-tokens;
   * recurrent (ssm/rglru) state integrates *every* processed token and the
@@ -97,6 +103,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.traversal import delayed_structure
 from repro.core.trees import DraftTree
@@ -117,7 +124,6 @@ from repro.serving.engine import (
     EngineConfig,
     SamplingParams,
     SpeculativeEngine,
-    _compiled_signatures,
     draw_token,
     to_verifier_dtype,
     verify_tree,
@@ -225,6 +231,12 @@ class BatchedSpeculativeEngine:
             "batched serving verifies per-stream on host (verify_on_device consumes " \
             "randomness differently and would break batch-vs-single exactness)"
         get_verifier(ecfg.verifier)  # fail loudly on unknown names, at build time
+        if mesh is not None:
+            # weights live on this engine's mesh devices from construction
+            # on, next to its pool: no step ever moves them across devices
+            on_mesh = NamedSharding(mesh, PartitionSpec())
+            target_params = jax.device_put(target_params, on_mesh)
+            draft_params = jax.device_put(draft_params, on_mesh)
         self.tc, self.tp = target_cfg, target_params
         self.dc, self.dp = draft_cfg, draft_params
         self.ecfg = ecfg
@@ -309,9 +321,11 @@ class BatchedSpeculativeEngine:
         # the tree pass — lanes the dispatch shipped vs real tree nodes
         # (pad_fraction = pad_nodes_total / tree_lanes_total); the ragged
         # layout exists to shrink it (benchmarks/batch_throughput.py gates
-        # it under the heterogeneous scenario)
+        # it under the heterogeneous scenario).  tree_calls_padded /
+        # tree_calls_ragged split target_calls by tree-pass layout.
         self.counters = {"target_calls": 0, "target_tokens": 0, "draft_calls": 0,
                          "draft_tokens": 0, "accepted": 0, "blocks": 0, "evicted": 0,
+                         "tree_calls_padded": 0, "tree_calls_ragged": 0,
                          "commit_calls": 0, "commit_ms": 0.0,
                          "blocks_reclaimed": 0, "admit_blocked": 0, "blocks_peak": 0,
                          "pad_nodes_total": 0, "tree_lanes_total": 0,
@@ -352,7 +366,15 @@ class BatchedSpeculativeEngine:
     def jit_compile_count(self) -> int:
         """Compiled signatures across this engine's jit cache — the cold-start
         compile budget bench_smoke.sh gates."""
-        return sum(_compiled_signatures(fn) for fn in self._jit_cache.values())
+        return sum(fn._cache_size() for fn in self._jit_cache.values())
+
+    def placement(self) -> dict[str, list[int]]:
+        """Ids of the devices holding each param tree and each pool."""
+        trees = {"target_params": self.tp, "draft_params": self.dp,
+                 "target_pool": self.tpool.cache, "draft_pool": self.dpool.cache}
+        return {name: sorted({d.id for leaf in jax.tree.leaves(tree)
+                              for d in leaf.devices()})
+                for name, tree in trees.items()}
 
     def _stage(self, name, shape, dtype, fill=0):
         """Reusable host staging buffer for per-step index arrays
@@ -818,14 +840,13 @@ class BatchedSpeculativeEngine:
         self.tpool.cache = cache
         real = sum(trees[s].n_nodes for s in active)
         self.counters["target_calls"] += 1
+        self.counters["tree_calls_padded"] += 1
         self.counters["target_tokens"] += real
         self.counters["tree_lanes_total"] += self.n_slots * Tpad
         self.counters["pad_nodes_total"] += self.n_slots * Tpad - real
         p_dev = self._warp(logits)
         for arr in (p_dev, hidden):
-            start_copy = getattr(arr, "copy_to_host_async", None)
-            if start_copy is not None:
-                start_copy()
+            arr.copy_to_host_async()
         return p_dev, hidden
 
     def _ragged_layout(self, active, trees):
@@ -880,14 +901,13 @@ class BatchedSpeculativeEngine:
         self.tpool.cache = cache
         real = sum(trees[s].n_nodes for s in active)
         self.counters["target_calls"] += 1
+        self.counters["tree_calls_ragged"] += 1
         self.counters["target_tokens"] += real
         self.counters["tree_lanes_total"] += Npad
         self.counters["pad_nodes_total"] += Npad - real
         p_dev = self._warp(logits)
         for arr in (p_dev, hidden):
-            start_copy = getattr(arr, "copy_to_host_async", None)
-            if start_copy is not None:
-                start_copy()
+            arr.copy_to_host_async()
         return p_dev, hidden
 
     def _commit_tables(self, active, node_paths):
@@ -1616,7 +1636,11 @@ class ShardedBatchedSpeculativeEngine:
         """Compile budget of the whole sharded deployment: every shard's jit
         cache plus the engine-level grouped-commit cache."""
         return (sum(sh.jit_compile_count() for sh in self.shards)
-                + sum(_compiled_signatures(fn) for fn in self._jit_cache.values()))
+                + sum(fn._cache_size() for fn in self._jit_cache.values()))
+
+    def placement(self) -> list[dict[str, list[int]]]:
+        """Per shard, the ids of the devices holding its params and pools."""
+        return [sh.placement() for sh in self.shards]
 
     def _finish_order(self, sis: list[int]) -> list[int]:
         """The order shards' in-flight steps are VERIFIED in.  Shards are

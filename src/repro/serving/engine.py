@@ -69,15 +69,6 @@ def verify_tree(tree: DraftTree, verifier: str, rng: np.random.Generator):
     return get_verifier(verifier).verify(tree, rng)
 
 
-def _compiled_signatures(fn) -> int:
-    """Number of XLA compilations a ``jax.jit`` wrapper holds.  Falls back to
-    counting the wrapper itself where jax does not expose the cache size."""
-    try:
-        return int(fn._cache_size())
-    except (AttributeError, TypeError):
-        return 1
-
-
 def fork_cache(cfg, cache: dict, K: int) -> dict:
     """Replicate a single-stream cache K ways along its batch axis.
 
@@ -137,7 +128,7 @@ class SpeculativeEngine:
         """Compiled signatures across this engine's jit cache — the cold-start
         compile budget bench_smoke.sh gates (one cache entry can hold several
         compilations when a name is reused across shapes/dtypes)."""
-        return sum(_compiled_signatures(fn) for fn in self._jit_cache.values())
+        return sum(fn._cache_size() for fn in self._jit_cache.values())
 
     def _warp(self, logits):
         return warp_logits(logits, self.sampling.temperature, self.sampling.top_p)
@@ -265,6 +256,8 @@ class SpeculativeEngine:
                 self.counters["draft_calls"] += 1
                 self.counters["draft_tokens"] += K
                 dists_b = np.asarray(self._warp(logits[:, 0]))
+                # branch k's next token is drawn from its newest node's q
+                cur_q = dists_b
                 for k in range(K):
                     tokens.append(ts[k])
                     parent.append(branch_nodes[k])

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ops import pool_commit_kv
+from repro.kernels.ops import interpret_mode, pool_commit_kv
 from repro.models.cache import merge_streams, paged_phys_slots
 from repro.models.transformer import forward
 
@@ -262,7 +262,6 @@ def make_pool_commit_step(cfg, Tpad: int):
     src == dst).  pos/len/block_tbl stay logical and untouched by the move.
     """
     use_pallas = cfg.attention_impl == "pallas"
-    interpret = cfg.kernel_interpret
 
     def commit(cache, node_path, path_len, C, active=None):
         a = cache["attn"]
@@ -290,13 +289,13 @@ def make_pool_commit_step(cfg, Tpad: int):
                 vf = v.reshape((nl, 1, v.shape[1] * block) + v.shape[3:])
                 kf, vf = pool_commit_kv(
                     kf, vf, srcf.astype(jnp.int32), dstf.astype(jnp.int32),
-                    use_pallas=use_pallas, interpret=interpret,
+                    use_pallas=use_pallas, interpret=interpret_mode(),
                 )
                 k, v = kf.reshape(k.shape), vf.reshape(v.shape)
             else:
                 k, v = pool_commit_kv(
                     k, v, src.astype(jnp.int32), dst.astype(jnp.int32),
-                    use_pallas=use_pallas, interpret=interpret,
+                    use_pallas=use_pallas, interpret=interpret_mode(),
                 )
             new_pos = pos.at[bidx, (C[:, None] + t[None, :]) % smax].set(-1)
             keep_valid = jj[None, :] <= path_len[:, None]

@@ -60,6 +60,19 @@ def test_batch_matches_single_tree_strategy(dense_models, verifier):
     assert outs == singles
 
 
+@pytest.mark.parametrize("action", [(2, 2, 2), (2, 0, 2), (3, 1, 3)])
+def test_batch_matches_single_multi_step_branches(dense_models, action):
+    """Branches of L2 >= 2 tokens: each branch's next token is drawn from its
+    newest node's draft distribution in both engines, so outputs stay
+    token-identical past the first branch step."""
+    tc, tp, dc, dp = dense_models
+    K, L1, L2 = action
+    ecfg = EngineConfig(verifier="specinfer", K=K, L1=L1, L2=L2, max_cache=128)
+    singles = _single_outputs(tc, tp, dc, dp, ecfg, PROMPTS, SEEDS, max_new=16)
+    beng = BatchedSpeculativeEngine(tc, tp, dc, dp, ecfg, n_slots=4)
+    assert beng.generate_batch(PROMPTS, max_new=16, seeds=SEEDS) == singles
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("verifier", ["specinfer", "traversal", "univer", "greedy_mpbv"])
 @pytest.mark.parametrize("cfg", [SSM_CFG, HYB_CFG], ids=["ssm", "hybrid"])

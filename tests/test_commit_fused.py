@@ -59,7 +59,7 @@ def _rand_case(rng, Tpad):
 
 
 def _fused(pool, paths, Cs, act, Tpad, attention_impl):
-    cfg = types.SimpleNamespace(attention_impl=attention_impl, kernel_interpret=True)
+    cfg = types.SimpleNamespace(attention_impl=attention_impl)
     P = next_pow2(max([len(p) for b, p in paths.items() if act[b]] + [1]))
     npath = np.zeros((B, P), np.int32)
     plen = np.zeros((B,), np.int32)

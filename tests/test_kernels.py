@@ -196,3 +196,34 @@ def test_ragged_tree_attention_property(n_streams, tail, seed):
     out = gqa_ragged_tree_attention(*args, interpret=True)
     ref = ragged_tree_attention_ref(*args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+def test_interpret_mode_follows_backend():
+    """Interpretation is decided once, from the backend: on the CPU backend
+    (every test run) kernels are interpreted; on a TPU they compile."""
+    from repro.kernels.ops import interpret_mode
+    from repro.models.config import ModelConfig
+
+    assert jax.default_backend() == "cpu"
+    assert interpret_mode() is True
+    # no config knob can pin interpretation on for a TPU run
+    assert not hasattr(ModelConfig(), "kernel_interpret")
+
+
+def test_kernel_wrappers_take_interpret_explicitly():
+    """No public kernel entry point defaults ``interpret``: every call site
+    states it, so none inherits interpretation silently."""
+    import inspect
+
+    from repro.kernels import commit_kv, decode_attention, ops, tree_attention
+
+    wrappers = [ops.pool_commit_kv, ops.gqa_tree_attention, ops.gqa_paged_tree_attention,
+                ops.gqa_ragged_tree_attention, ops.gqa_paged_decode_attention,
+                ops.gqa_decode_attention, commit_kv.commit_kv,
+                tree_attention.tree_attention, tree_attention.paged_tree_attention,
+                tree_attention.ragged_paged_tree_attention,
+                decode_attention.decode_attention, decode_attention.paged_decode_attention]
+    for fn in wrappers:
+        param = inspect.signature(fn).parameters["interpret"]
+        assert param.kind is inspect.Parameter.KEYWORD_ONLY, fn
+        assert param.default is inspect.Parameter.empty, fn

@@ -170,7 +170,7 @@ def test_paged_commit_matches_dense(seed, Tpad):
     rng = np.random.default_rng(seed)
     dense, paged = _paired_pools(rng)
     args = _commit_args(rng, Tpad)
-    cfg = types.SimpleNamespace(attention_impl="xla", kernel_interpret=True)
+    cfg = types.SimpleNamespace(attention_impl="xla")
     commit = make_pool_commit_step(cfg, Tpad)
     want = _logical(commit(dense, *args))
     got = _logical(commit(paged, *args))
@@ -185,8 +185,8 @@ def test_paged_commit_pallas_kernel_path(seed, Tpad):
     rng = np.random.default_rng(seed)
     dense, paged = _paired_pools(rng)
     args = _commit_args(rng, Tpad)
-    xla = types.SimpleNamespace(attention_impl="xla", kernel_interpret=True)
-    pal = types.SimpleNamespace(attention_impl="pallas", kernel_interpret=True)
+    xla = types.SimpleNamespace(attention_impl="xla")
+    pal = types.SimpleNamespace(attention_impl="pallas")
     want = _logical(make_pool_commit_step(xla, Tpad)(dense, *args))
     got = _logical(make_pool_commit_step(pal, Tpad)(paged, *args))
     for key in want:

@@ -412,3 +412,57 @@ def test_bin_packing_homogeneous_hints_degrade_to_least_loaded(dense_models):
             for p, sd in zip(PROMPTS, SEEDS)]
     assert [eng.shard_of(r) for r in rids] == [0, 1, 0, 1]
     eng.run()
+
+
+_FOUR_DEVICE_CHILD = """
+import json, jax
+from repro.models.config import ModelConfig
+from repro.models.transformer import init_params
+from repro.serving.batch_engine import BatchedSpeculativeEngine, ShardedBatchedSpeculativeEngine
+from repro.serving.engine import EngineConfig
+t = ModelConfig(name="t", arch_type="dense", n_layers=2, d_model=64, n_heads=4,
+                n_kv_heads=2, d_ff=96, vocab=32, dtype="float32")
+d = t.replace(name="d", n_layers=1, d_model=32)
+tp, dp = init_params(t, jax.random.PRNGKey(0)), init_params(d, jax.random.PRNGKey(1))
+ecfg = EngineConfig(verifier="specinfer", K=2, L1=1, L2=1, max_cache=64)
+prompts, seeds = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [3, 1]], [20, 21, 22, 23]
+one = BatchedSpeculativeEngine(t, tp, d, dp, ecfg, n_slots=4)
+eng = ShardedBatchedSpeculativeEngine(t, tp, d, dp, ecfg, n_slots=4, data_shards=4)
+same = eng.generate_batch(prompts, 6, seeds) == one.generate_batch(prompts, 6, seeds)
+print(json.dumps({"devices": len(jax.devices()), "placement": eng.placement(),
+                  "unsharded": one.placement(), "same": same}))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_run():
+    """The 4-shard engine on 4 virtual CPU devices.  The device count is
+    fixed when a process first touches the backend, so the run goes to a
+    child process with the flag set; this process keeps its one device."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                          + " --xla_force_host_platform_device_count=4").strip(),
+               PYTHONPATH=os.pathsep.join([src, os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_CHILD], env=env,
+                         capture_output=True, text=True, timeout=300, check=True)
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_shards_hold_params_and_pools_on_own_device(four_device_run):
+    """Each shard's target/draft params and pools sit on that shard's own
+    device (placed once at construction, so no step moves weights across
+    devices), the shards cover four distinct devices, and the tokens equal
+    the unsharded engine's on device 0."""
+    run = four_device_run
+    assert run["devices"] == 4
+    for i, placed in enumerate(run["placement"]):
+        assert placed == {"target_params": [i], "draft_params": [i],
+                          "target_pool": [i], "draft_pool": [i]}
+    assert set(run["unsharded"]["target_pool"]) == {0}
+    assert run["same"]
