@@ -21,11 +21,9 @@ Reported tokens/sec is aggregate (all requests' emitted tokens / wall).
 Wall-clock excludes compilation: each engine first runs the whole workload
 untimed (populating its jit cache for every shape bucket the workload
 hits), then the timed pass re-runs it — so the comparison prices the
-steady-state serving loop.  The warmup pass doubles as the commit profiler
-(it blocks on every fused commit for an honest ``commit_ms``) and as the
-occupancy probe; the timed pass runs unblocked, so commit dispatches
-overlap host work exactly as they do in production for BOTH stepping
-modes.  The batched and pipelined timed reps are interleaved in
+steady-state serving loop.  The warmup pass doubles as the occupancy
+probe; both passes run unblocked, so commit dispatches overlap host work
+exactly as they do in production for BOTH stepping modes.  The batched and pipelined timed reps are interleaved in
 alternating order (``_interleaved_timed``) so machine drift cannot
 masquerade as a stepping-mode difference.  Outputs are seeded
 identically, so the batched and pipelined columns also re-check the
@@ -116,22 +114,19 @@ def run_sequential(cfg, tp, dcfg, dp, ecfg, sampling, prompts, max_new, seeds, r
 
 
 _OVERLAP_KEYS = ("pipeline_ahead", "pipeline_stalls", "pipeline_iterations")
-_WARM_KEYS = ("commit_calls", "commit_ms", "blocks_reclaimed", "blocks_peak") \
-    + _OVERLAP_KEYS
+_WARM_KEYS = ("commit_calls", "blocks_reclaimed", "blocks_peak") + _OVERLAP_KEYS
 
 
 def prepare_batched(cfg, tp, dcfg, dp, ecfg, sampling, prompts, max_new, seeds,
                     paged=True, block_size=64, pipeline=False, data_shards=1,
                     ragged=True, selector=None):
-    """Build a batched (or sharded) engine, run the warmup/profiling pass and
-    return ``(eng, workload, commit_stats, peak_occ)`` ready for timing.
+    """Build a batched (or sharded) engine, run the warmup pass and return
+    ``(eng, workload, commit_stats, peak_occ)`` ready for timing.
 
-    The warmup pass compiles every shape bucket, profiles commits honestly
-    (``profile_commits`` blocks on each fused commit — doing that in the
-    timed pass would serialize the very overlap the pipeline exists to
-    create) and probes pool occupancy whenever the used-block peak advances.
-    The workload repeats deterministically, so the warmup's commit cost and
-    peak occupancy are the timed pass's too."""
+    The warmup pass compiles every shape bucket, counts commits and probes
+    pool occupancy whenever the used-block peak advances.  The workload
+    repeats deterministically, so the warmup's commit count and peak
+    occupancy are the timed pass's too."""
     if data_shards > 1:
         eng = ShardedBatchedSpeculativeEngine(
             cfg, tp, dcfg, dp, ecfg, sampling, selector=selector,
@@ -152,7 +147,6 @@ def prepare_batched(cfg, tp, dcfg, dp, ecfg, sampling, prompts, max_new, seeds,
         outs = eng.run()
         return [outs[r]["tokens"] for r in rids]
 
-    eng.profile_commits = True
     t0 = time.time()
     for p, sd in zip(prompts, seeds):
         eng.submit(list(p), max_new=max_new, seed=sd)
@@ -169,13 +163,11 @@ def prepare_batched(cfg, tp, dcfg, dp, ecfg, sampling, prompts, max_new, seeds,
     warm = {"warmup_secs": time.time() - t0,
             "compile_count": eng.jit_compile_count()}
     commit_stats = {k: eng.counters[k] for k in
-                    ("commit_calls", "commit_ms", "blocks_peak", "blocks_reclaimed")}
+                    ("commit_calls", "blocks_peak", "blocks_reclaimed")}
     # the per-shard peaks tell the scheduler-balance story the aggregate hides
     commit_stats["shard_blocks_peak"] = (
         [e.counters["blocks_peak"] for e in engines] if data_shards > 1 else None)
-    # From here the steady-state serving loop runs with commits dispatched
-    # async; zero the warmup's tallies so the timed pass reports its own.
-    eng.profile_commits = False
+    # zero the warmup's tallies so the timed pass reports its own
     eng.reset_counters(_WARM_KEYS)
     return eng, workload, commit_stats, peak["occ"], warm
 
@@ -189,7 +181,7 @@ def run_batched(cfg, tp, dcfg, dp, ecfg, sampling, prompts, max_new, seeds,
         data_shards=data_shards, ragged=ragged)
     outs, dt = _best_timed(workload, reps)
     counters = dict(eng.counters)
-    counters.update(commit_stats)  # report the honest (blocked) commit numbers
+    counters.update(commit_stats)  # the warmup pass's commit and block numbers
     return outs, dt, counters, occ
 
 
@@ -440,7 +432,6 @@ def main(argv=None):
             pipe_exact = all(a == b for a, b in zip(outs_s, outs_p))
         rows.append((n, tok / dt_s, tok / dt_b,
                      tok / dt_p if dt_p else None, exact and pipe_exact))
-        cc = max(counters["commit_calls"], 1)
         pool_note = ""
         if occ:
             # blocks_peak and blocks_total both describe the TARGET arena
@@ -459,8 +450,7 @@ def main(argv=None):
                  f"   pad: {pad_fraction:.2f}"
                  + ("(" + "/".join(f"{f:.2f}" for f in shard_pad_fraction) + ")"
                     if shard_pad_fraction else "")
-                 + f"   commit: {counters['commit_calls']} calls, "
-                 f"{counters['commit_ms']:.1f} ms ({counters['commit_ms'] / cc:.2f} ms/call)")
+                 + f"   commit: {counters['commit_calls']} calls")
         if pcounters:
             line += (f"   overlap: {pcounters['pipeline_ahead']} ahead, "
                      f"{pcounters['pipeline_stalls']} stalls / "
@@ -483,7 +473,6 @@ def main(argv=None):
             "exact": bool(exact),
             "pipeline_exact": bool(pipe_exact),
             "commit_calls": counters["commit_calls"],
-            "commit_ms": counters["commit_ms"],
             "blocks_peak": counters["blocks_peak"],
             "blocks_reclaimed": counters["blocks_reclaimed"],
             "shard_blocks_peak": counters.get("shard_blocks_peak"),

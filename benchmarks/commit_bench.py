@@ -138,7 +138,7 @@ def run(args):
             {"streams": sizes, "layers": L, "smax": S, "kv_heads": H,
              "head_dim": hd, "tpad": Tpad, "iters": args.iters,
              "impl": args.impl, "seed": args.seed},
-            [{"streams": n, "commit_ms": {"sequential": s, "fused": f},
+            [{"streams": n, "median_ms": {"sequential": s, "fused": f},
               "speedup_fused_vs_sequential": s / f} for n, s, f in rows],
         )
         print(f"wrote {args.json}")

@@ -165,7 +165,7 @@ def write_bench_json(path: str, name: str, config: dict, results: list[dict]) ->
 
     ``config`` holds the knobs that define the run (arch, verifier, action,
     sizes); each ``results`` row holds the measured numbers for one point
-    (tokens/sec per mode, commit_ms, blocks peak, exactness booleans).  The
+    (tokens/sec per mode, commit calls, blocks peak, exactness booleans).  The
     writer is schema-versioned so gates can refuse documents they do not
     understand instead of misreading them.
     """

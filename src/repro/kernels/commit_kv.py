@@ -83,4 +83,5 @@ def commit_kv(k, v, src, dst, *, interpret: bool):
         ],
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
+        name="commit_kv",
     )(src, dst, k, v)

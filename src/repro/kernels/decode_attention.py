@@ -112,6 +112,7 @@ def paged_decode_attention(q, k_arena, v_arena, tbl, lengths, *, window: int = 0
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, R, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(tbl, lengths.reshape(BH), q, k_arena, v_arena)
 
 
@@ -141,4 +142,5 @@ def decode_attention(q, k, v, lengths, *, block_k: int = 1024, window: int = 0, 
             pltpu.VMEM((R, D), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lengths.reshape(BH), q, k, v)

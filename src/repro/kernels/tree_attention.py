@@ -139,6 +139,7 @@ def paged_tree_attention(q, k_arena, v_arena, tbl, mask, *, interpret: bool):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=interpret,
+        name="paged_tree_attention",
     )(tbl, q, k_arena, v_arena, _block_major(mask, block))
 
 
@@ -194,6 +195,7 @@ def ragged_paged_tree_attention(q, k_arena, v_arena, tbl, owners, mask, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, Np, D), q.dtype),
         interpret=interpret,
+        name="ragged_paged_tree_attention",
     )(owners, tbl, q, k_arena, v_arena, _block_major(mask, block))
 
 
@@ -227,4 +229,5 @@ def tree_attention(q, k, v, mask, *, block_k: int = 512, interpret: bool):
             pltpu.VMEM((T, D), jnp.float32),
         ],
         interpret=interpret,
+        name="tree_attention",
     )(q, k, v, _block_major(mask, block_k))

@@ -138,6 +138,7 @@ from repro.serving.serve_step import (
     make_pool_tree_step,
     next_pow2 as _next_pow2,
 )
+from repro.serving.tracing import jit_named, span, step_span, traced
 
 RECURRENT = ("ssm", "hybrid")
 
@@ -148,6 +149,7 @@ class BatchRequest:
     prompt: list
     max_new: int
     seed: int
+    t_submit: float  # perf_counter at submit(), for counters["queue_ms"]
 
 
 @dataclass
@@ -309,10 +311,7 @@ class BatchedSpeculativeEngine:
         self._staging = StagingBuffers(banks=2 if pipeline else 1)
         self._pending_next: PendingStep | None = None
         self._drained_events: list[dict] = []  # retired by submit(), not yet returned
-        # commit_ms times the dispatch only unless profile_commits is set
-        # (benchmarks set it): blocking on the commit every step would
-        # serialize host bookkeeping against the device op it just saved.
-        self.profile_commits = False
+        self._step_no = 0  # step() calls, the step number of the serve:step span
         # pipeline_iterations counts every pipeline-ahead decision point, and
         # each decision either runs ahead or stalls — so
         # pipeline_ahead + pipeline_stalls == pipeline_iterations holds by
@@ -323,14 +322,22 @@ class BatchedSpeculativeEngine:
         # layout exists to shrink it (benchmarks/batch_throughput.py gates
         # it under the heterogeneous scenario).  tree_calls_padded /
         # tree_calls_ragged split target_calls by tree-pass layout.
+        # readback_bytes: device->host bytes of every blocking read
+        # (``_fetch``).  steps_begun / steps_rewound / steps_drained: begun
+        # steps, and those a mid-run submit() rewound (abort_step) or
+        # finished early; once nothing is pending, steps_begun == finished
+        # steps + steps_rewound.  admitted / queue_ms: requests admitted and
+        # their summed submit()-to-admission wait.
         self.counters = {"target_calls": 0, "target_tokens": 0, "draft_calls": 0,
                          "draft_tokens": 0, "accepted": 0, "blocks": 0, "evicted": 0,
                          "tree_calls_padded": 0, "tree_calls_ragged": 0,
-                         "commit_calls": 0, "commit_ms": 0.0,
+                         "commit_calls": 0,
                          "blocks_reclaimed": 0, "admit_blocked": 0, "blocks_peak": 0,
                          "pad_nodes_total": 0, "tree_lanes_total": 0,
                          "pipeline_ahead": 0, "pipeline_stalls": 0,
-                         "pipeline_iterations": 0}
+                         "pipeline_iterations": 0, "readback_bytes": 0,
+                         "steps_begun": 0, "steps_rewound": 0, "steps_drained": 0,
+                         "admitted": 0, "queue_ms": 0.0}
 
     def reset_counters(self, keys) -> None:
         """Zero the named counters (shared surface with the sharded engine —
@@ -355,13 +362,19 @@ class BatchedSpeculativeEngine:
         return bs
 
     def _jit(self, name, fn, donate_argnums=None):
-        """Per-engine jit cache.  ``donate_argnums`` marks pool args whose
+        """Per-engine jit cache; the program is named after its key
+        (tracing.jit_named).  ``donate_argnums`` marks pool args whose
         buffers XLA may update in place (the commit path donates the pool so
         committing moves lanes instead of copying the pool)."""
-        if name not in self._jit_cache:
-            kw = {} if donate_argnums is None else {"donate_argnums": donate_argnums}
-            self._jit_cache[name] = jax.jit(fn, **kw)
-        return self._jit_cache[name]
+        return jit_named(self._jit_cache, name, fn, donate_argnums)
+
+    def _fetch(self, x, what: str) -> np.ndarray:
+        """Block on a device array and copy it to the host, inside a
+        ``serve:wait.<what>`` span, counting the bytes copied."""
+        with span(f"serve:wait.{what}"):
+            a = np.asarray(x)
+        self.counters["readback_bytes"] += a.nbytes
+        return a
 
     def jit_compile_count(self) -> int:
         """Compiled signatures across this engine's jit cache — the cold-start
@@ -464,14 +477,17 @@ class BatchedSpeculativeEngine:
             #     request queued, exactly as the synchronous engine would.
             pending, self._pending_next = self._pending_next, None
             if pending.boundary_evicted:
-                self._drained_events.extend(
-                    self.finish_step(pending, pipeline_ahead=False))
+                self.counters["steps_drained"] += 1
+                with span("serve:drain"):
+                    self._drained_events.extend(
+                        self.finish_step(pending, pipeline_ahead=False))
             else:
                 self.abort_step(pending)
         rid = self._next_rid
         self._next_rid += 1
         self.queue.append(BatchRequest(rid, list(prompt), max_new,
-                                       self.ecfg.seed if seed is None else seed))
+                                       self.ecfg.seed if seed is None else seed,
+                                       time.perf_counter()))
         return rid
 
     def can_admit(self, prompt_len: int) -> bool:
@@ -499,7 +515,7 @@ class BatchedSpeculativeEngine:
         if self._recurrent(cfg):
             fn = self._jit(f"{name}_prefill_{T}", partial(forward, cfg=cfg, mode="full"))
             _, row, ex = fn(params, tokens=jnp.asarray(np.asarray(ctx, np.int32)[None]), cache=row)
-            return row, np.asarray(ex["hidden"][0, T - 1])
+            return row, self._fetch(ex["hidden"][0, T - 1], "prefill")
         # bucket the pad, but never past the ring: a padded pass longer than
         # smax would wrap and overwrite the committed prefix it just wrote
         Tp = min(_next_pow2(T), self.ecfg.max_cache)
@@ -508,7 +524,7 @@ class BatchedSpeculativeEngine:
         fn = self._jit(f"{name}_prefill_p{Tp}", partial(forward, cfg=cfg, mode="full"))
         _, row, ex = fn(params, tokens=jnp.asarray(toks), cache=row,
                         lens=jnp.asarray([T], jnp.int32))
-        return row, np.asarray(ex["hidden"][0, T - 1])
+        return row, self._fetch(ex["hidden"][0, T - 1], "prefill")
 
     def _paged_pools(self) -> list[PagedCachePool]:
         return [p for p in (self.tpool, self.dpool) if isinstance(p, PagedCachePool)]
@@ -521,6 +537,7 @@ class BatchedSpeculativeEngine:
             {0: (self.ecfg.K, self.ecfg.L1, self.ecfg.L2)})
         return min(-(-(prompt_len + tpad0) // self.block_size), self.max_blocks)
 
+    @traced("serve:admit")
     def _admit(self):
         while self.queue and self.tpool.free_slots:
             req = self.queue[0]
@@ -547,9 +564,13 @@ class BatchedSpeculativeEngine:
                     self.counters["admit_blocked"] += 1
                     break  # FIFO: the head blocks the queue until blocks free up
             self.queue.pop(0)
+            self.counters["admitted"] += 1
+            self.counters["queue_ms"] += (time.perf_counter() - req.t_submit) * 1e3
             ctx = req.prompt[:-1]
-            trow, h_p = self._prefill_row(self.tc, self.tp, ctx, "tgt")
-            drow, h_q = self._prefill_row(self.dc, self.dp, ctx, "drf")
+            with span("serve:prefill", rid=req.rid):
+                trow, h_p = self._prefill_row(self.tc, self.tp, ctx, "tgt")
+            with span("serve:prefill", rid=req.rid):
+                drow, h_q = self._prefill_row(self.dc, self.dp, ctx, "drf")
             slot = self.tpool.admit(trow, ctx_len=len(ctx))
             slot_d = self.dpool.admit(drow, ctx_len=len(ctx))
             assert slot == slot_d
@@ -584,6 +605,7 @@ class BatchedSpeculativeEngine:
 
     # ------------------------------------------------------------ drafting ---
 
+    @traced("serve:ingest")
     def _ingest_deltas(self, active):
         """Advance the draft pool over each stream's newly committed tokens.
         Returns per-slot (q0 dist, draft hidden at the new root)."""
@@ -601,8 +623,8 @@ class BatchedSpeculativeEngine:
                 logits, sub, ex = fn(self.dp, tokens=jnp.asarray(toks_p), cache=sub)
                 trims.append(gather_streams(sub, list(range(len(rows)))))
                 all_rows.extend(rows)
-                w = np.asarray(self._warp(logits))
-                hid = np.asarray(ex["hidden"])
+                w = self._fetch(self._warp(logits), "ingest")
+                hid = self._fetch(ex["hidden"], "ingest")
                 for i, s in enumerate(rows):
                     q0[s] = w[i, L - 1]
                     hq[s] = hid[i, L - 1]
@@ -624,8 +646,8 @@ class BatchedSpeculativeEngine:
             logits, cache, hidden = fn(self.dp, self.dpool.cache, jnp.asarray(toks),
                                        jnp.asarray(lens))
             self.dpool.cache = cache
-            w = np.asarray(self._warp(logits))
-            hid = np.asarray(hidden)
+            w = self._fetch(self._warp(logits), "ingest")
+            hid = self._fetch(hidden, "ingest")
             for s in active:
                 q0[s] = w[s, lens[s] - 1]
                 hq[s] = hid[s, lens[s] - 1]
@@ -716,6 +738,7 @@ class BatchedSpeculativeEngine:
                 out[name] = pool.occupancy(fr)
         return out
 
+    @traced("serve:draft")
     def _draft_trees(self, active, acts, q0, pads):
         """Lockstep-draft every stream's (K, L1, L2) delayed tree on a local
         copy of the draft pool (discarded after, like the single engine)."""
@@ -741,7 +764,7 @@ class BatchedSpeculativeEngine:
                     trunk_tok[s].append(t)
                     n_live += 1
             logits, dwork = step_fn(self.dp, dwork, jnp.asarray(toks), jnp.asarray(keep))
-            w = np.asarray(self._warp(logits[:, 0]))
+            w = self._fetch(self._warp(logits[:, 0]), "draft")
             for s in active:
                 if keep[s]:
                     cur[s] = w[s]
@@ -771,7 +794,7 @@ class BatchedSpeculativeEngine:
                             branch_tok[s][k].append(t)
                             n_live += 1
                 logits, dfork, _ = bstep(self.dp, tokens=jnp.asarray(toks), cache=dfork)
-                w = np.asarray(self._warp(logits[:, 0]))
+                w = self._fetch(self._warp(logits[:, 0]), "draft")
                 for s in active:
                     K, _, L2 = acts[s]
                     if j < L2:
@@ -813,6 +836,7 @@ class BatchedSpeculativeEngine:
 
     # ----------------------------------------------------- target: tree -----
 
+    @traced("serve:dispatch")
     def _target_tree_dispatch(self, active, trees, Tpad):
         """Dispatch ONE padded tree-masked target pass over every active row
         and return its warped logits / hidden states as DEVICE arrays (with
@@ -863,6 +887,7 @@ class BatchedSpeculativeEngine:
             off += -(-n // align) * align
         return offs, _next_pow2(max(off, align))
 
+    @traced("serve:dispatch")
     def _target_tree_dispatch_ragged(self, active, trees, roffs):
         """Ragged counterpart of ``_target_tree_dispatch``: ONE flat
         node-major tree pass over every active stream's tree, no per-row
@@ -937,13 +962,9 @@ class BatchedSpeculativeEngine:
         npath, plen, Cb, act, P = self._commit_tables(active, node_paths)
         fn = self._jit(f"commit_T{Tpad}_P{P}",
                        make_pool_commit_step(self.tc, Tpad), donate_argnums=0)
-        t0 = time.perf_counter()
         self.tpool.cache = fn(self.tpool.cache, jnp.asarray(npath), jnp.asarray(plen),
                               jnp.asarray(Cb), jnp.asarray(act))
-        if self.profile_commits:
-            jax.block_until_ready(self.tpool.cache)
         self.counters["commit_calls"] += 1
-        self.counters["commit_ms"] += (time.perf_counter() - t0) * 1e3
 
     # --------------------------------------------------- target: replay -----
 
@@ -976,7 +997,7 @@ class BatchedSpeculativeEngine:
             logits, sub, _ = fn(self.tp, tokens=jnp.asarray(toks_p), cache=sub)
             trims.append(gather_streams(sub, list(range(len(rows)))))
             trunk_rows.extend(rows)
-            w = np.asarray(self._warp(logits))
+            w = self._fetch(self._warp(logits), "tree")
             for i, s in enumerate(rows):
                 trunk, _, _ = structs[s]
                 p_host[s][0] = w[i, 0]
@@ -1009,7 +1030,7 @@ class BatchedSpeculativeEngine:
                 sub = gather_streams(fork, frows_p)
                 fn = self._jit(f"tgt_branch_g{L2}k{Kp}", partial(forward, cfg=self.tc, mode="decode"))
                 logits, _, _ = fn(self.tp, tokens=jnp.asarray(btoks_p), cache=sub)
-                pb = np.asarray(self._warp(logits))
+                pb = self._fetch(self._warp(logits), "tree")
                 for i, (s, path) in enumerate(meta):
                     for j, v in enumerate(path):
                         p_host[s][v] = pb[i, j]
@@ -1039,19 +1060,16 @@ class BatchedSpeculativeEngine:
             _, sub, ex = fn(self.tp, tokens=jnp.asarray(toks_p), cache=sub)
             trims.append(gather_streams(sub, list(range(len(rows)))))
             all_rows.extend(rows)
-            hid = np.asarray(ex["hidden"])
+            hid = self._fetch(ex["hidden"], "hidden")
             for i, s in enumerate(rows):
                 hid_last[s] = hid[i, L - 1]
-        t0 = time.perf_counter()
         self.tpool.cache = self._scatter_rows(snapshot, trims, all_rows, donate=True)
-        if self.profile_commits:
-            jax.block_until_ready(self.tpool.cache)
         self.counters["commit_calls"] += 1
-        self.counters["commit_ms"] += (time.perf_counter() - t0) * 1e3
         return hid_last
 
     # ---------------------------------------------------------------- step ---
 
+    @traced("serve:begin")
     def begin_step(self) -> PendingStep | None:
         """The DISPATCH half of a step: run the scheduling boundary (admit
         queued requests, capacity-evict, map paged blocks), then dispatch
@@ -1130,6 +1148,7 @@ class BatchedSpeculativeEngine:
                 D0 = {s: len(self.streams[s]["committed"])
                          - len(self.streams[s]["draft_delta"])
                       for s in active}
+        self.counters["steps_begun"] += 1
         q0, hq = self._ingest_deltas(active)
         trees = self._draft_trees(active, acts, q0, pads)
         if self.strategy == "tree":
@@ -1156,6 +1175,7 @@ class BatchedSpeculativeEngine:
                            rng_state=rng_state, D0=D0,
                            boundary_evicted=boundary_evicted)
 
+    @traced("serve:verify")
     def verify_step(self, pending: PendingStep) -> VerifiedStep:
         """The VERIFY phase: block on the tree-pass logits future and run
         every stream's host-side accept/reject walk.  Consumes per-stream
@@ -1168,7 +1188,7 @@ class BatchedSpeculativeEngine:
         active, trees = pending.active, pending.trees
         accepted, corr = {}, {}
         if self.strategy == "tree":
-            p_all = np.asarray(pending.p_dev)
+            p_all = self._fetch(pending.p_dev, "tree")
             node_paths = {}
             for s in active:
                 tree = trees[s]
@@ -1188,6 +1208,7 @@ class BatchedSpeculativeEngine:
             accepted[s], corr[s] = acc, int(c)
         return VerifiedStep(pending, accepted, corr)
 
+    @traced("serve:commit")
     def commit_step(self, v: VerifiedStep) -> None:
         """The COMMIT phase: ONE fused, pool-donating call compacts every
         row's accepted path (tree strategy), or the grouped replay
@@ -1210,7 +1231,7 @@ class BatchedSpeculativeEngine:
         departed rows are skipped."""
         pending = v.pending
         if self.strategy == "tree":
-            hid_all = np.asarray(pending.hid_dev)
+            hid_all = self._fetch(pending.hid_dev, "hidden")
             for s in pending.active:
                 if s not in self.streams:
                     continue
@@ -1225,6 +1246,7 @@ class BatchedSpeculativeEngine:
                 if s in self.streams:
                     self.streams[s]["h_prev_p"] = v.hid_last[s]
 
+    @traced("serve:retire")
     def retire_step(self, v: VerifiedStep, pipeline_ahead: bool | None = None) -> list[dict]:
         """The RETIRE phase: token bookkeeping, the pipeline-ahead decision,
         then the host tail (hidden-state readback, releasing finished
@@ -1295,13 +1317,15 @@ class BatchedSpeculativeEngine:
         begin_step + finish_step; in pipelined mode it first consumes the
         step begun ahead by the previous ``finish_step`` (and surfaces any
         events a mid-run ``submit`` retired on its behalf)."""
-        events, self._drained_events = self._drained_events, []
-        pending, self._pending_next = self._pending_next, None
-        if pending is None:
-            pending = self.begin_step()
-        if pending is None:
-            return events
-        return events + self.finish_step(pending)
+        self._step_no += 1
+        with step_span(self._step_no):
+            events, self._drained_events = self._drained_events, []
+            pending, self._pending_next = self._pending_next, None
+            if pending is None:
+                pending = self.begin_step()
+            if pending is None:
+                return events
+            return events + self.finish_step(pending)
 
     def drain_pipeline(self) -> list[dict]:
         """Finish the begun-ahead step WITHOUT beginning another — the drain
@@ -1313,6 +1337,7 @@ class BatchedSpeculativeEngine:
             return []
         return self.finish_step(pending, pipeline_ahead=False)
 
+    @traced("serve:abort")
     def abort_step(self, pending: PendingStep) -> None:
         """Rewind a begun step as if it never dispatched (pipelined mode):
         restore every active stream's rng snapshot, rewind the draft pool —
@@ -1328,9 +1353,10 @@ class BatchedSpeculativeEngine:
         ``begin_step`` (admissions, evictions, block mappings) are
         scheduling events that stand; dead mappings are recycled by the
         normal pressure path.  Work counters also stand — they count
-        dispatched work."""
+        dispatched work — and ``steps_rewound`` counts the rewind."""
         assert pending.rng_state is not None, \
             "abort_step needs the rng snapshots only pipelined begin_step records"
+        self.counters["steps_rewound"] += 1
         if pending is self._pending_next:
             self._pending_next = None
         for s, state in pending.rng_state.items():
@@ -1393,7 +1419,7 @@ class BatchedSpeculativeEngine:
         fn = self._jit(f"{name}_peek_{T}", partial(forward, cfg=cfg, mode="decode"))
         logits, _, _ = fn(params, tokens=jnp.asarray(np.asarray(toks, np.int32)[None]),
                           cache=sub)
-        return np.asarray(self._warp(logits[0]))[-1]
+        return self._fetch(self._warp(logits[0]), "draft" if name == "drf" else "tree")[-1]
 
     def peek_draft_dist(self, stream, ctx: list[int]) -> np.ndarray:
         """q(. | committed + ctx) for a pooled stream, functional.
@@ -1536,10 +1562,11 @@ class ShardedBatchedSpeculativeEngine:
         devs = [tuple(sh.mesh.devices.flat) for sh in self.shards]
         self._colocated = all(d == devs[0] for d in devs)
         self._jit_cache: dict = {}
-        # engine-level commit counters: a grouped commit is ONE dispatch
+        self._step_no = 0  # step() calls, the step number of the serve:step span
+        # engine-level commit counter: a grouped commit is ONE dispatch
         # that belongs to no single shard (the counters property merges
-        # these into the summed per-shard view)
-        self._counters = {"commit_calls": 0, "commit_ms": 0.0}
+        # it into the summed per-shard view)
+        self._counters = {"commit_calls": 0}
 
     # --------------------------------------------------------- scheduling ---
 
@@ -1627,10 +1654,7 @@ class ShardedBatchedSpeculativeEngine:
     def _jit(self, name, fn, donate_argnums=None):
         """Engine-level jit cache for the grouped cross-shard commit (the
         shards keep their own caches for everything shard-local)."""
-        if name not in self._jit_cache:
-            kw = {} if donate_argnums is None else {"donate_argnums": donate_argnums}
-            self._jit_cache[name] = jax.jit(fn, **kw)
-        return self._jit_cache[name]
+        return jit_named(self._jit_cache, name, fn, donate_argnums)
 
     def jit_compile_count(self) -> int:
         """Compile budget of the whole sharded deployment: every shard's jit
@@ -1660,42 +1684,44 @@ class ShardedBatchedSpeculativeEngine:
         retire in shard order — the retire phase runs each shard's
         pipeline-ahead dispatch when pipelining, so the next iteration's
         device work is already in flight when this call returns."""
-        events = []
-        # phase 1 — begin: surface drained events, then dispatch every
-        # shard's step (consuming a begun-ahead step where one is pending)
-        # before any verification blocks on a device future
-        pendings: list = []
-        for si, sh in enumerate(self.shards):
-            drained, sh._drained_events = sh._drained_events, []
-            events.extend(self._collect(si, drained))
-            pending, sh._pending_next = sh._pending_next, None
-            if pending is None:
-                pending = sh.begin_step()
-            pendings.append(pending)
-        live = [si for si, p in enumerate(pendings) if p is not None]
-        # phase 2 — verify: per-stream host walks, one shard at a time,
-        # while the remaining shards' dispatched passes keep the device busy
-        verified = {si: self.shards[si].verify_step(pendings[si])
-                    for si in self._finish_order(live)}
-        # phase 3 — commit: one grouped dispatch across shards
-        self._commit_shards(verified)
-        # phase 4 — retire (shard order, so event order is deterministic
-        # regardless of the verify permutation)
-        for si in sorted(verified):
-            events.extend(self._collect(
-                si, self.shards[si].retire_step(verified[si])))
-        # a shard whose boundary came up empty can still have retired a
-        # stream there (capacity eviction) — surface its finished payloads
-        for si in range(self.data_shards):
-            if si not in verified:
-                events.extend(self._collect(si, []))
-        return events
+        self._step_no += 1
+        with step_span(self._step_no):
+            events = []
+            # phase 1 — begin: surface drained events, then dispatch every
+            # shard's step (consuming a begun-ahead step where one is pending)
+            # before any verification blocks on a device future
+            pendings: list = []
+            for si, sh in enumerate(self.shards):
+                drained, sh._drained_events = sh._drained_events, []
+                events.extend(self._collect(si, drained))
+                pending, sh._pending_next = sh._pending_next, None
+                if pending is None:
+                    pending = sh.begin_step()
+                pendings.append(pending)
+            live = [si for si, p in enumerate(pendings) if p is not None]
+            # phase 2 — verify: per-stream host walks, one shard at a time,
+            # while the remaining shards' dispatched passes keep the device busy
+            verified = {si: self.shards[si].verify_step(pendings[si])
+                        for si in self._finish_order(live)}
+            # phase 3 — commit: one grouped dispatch across shards
+            self._commit_shards(verified)
+            # phase 4 — retire (shard order, so event order is deterministic
+            # regardless of the verify permutation)
+            for si in sorted(verified):
+                events.extend(self._collect(
+                    si, self.shards[si].retire_step(verified[si])))
+            # a shard whose boundary came up empty can still have retired a
+            # stream there (capacity eviction) — surface its finished payloads
+            for si in range(self.data_shards):
+                if si not in verified:
+                    events.extend(self._collect(si, []))
+            return events
 
     def _commit_shards(self, verified: dict[int, VerifiedStep]) -> None:
         """Commit every verified shard's accepted paths.  Tree-strategy
         shards that share a device batch their staged index tables into ONE
         jitted, pool-donating dispatch (serve_step.make_group_commit_step)
-        — restoring single-shard ``commit_calls``/``commit_ms`` — and fall
+        — restoring single-shard ``commit_calls`` — and fall
         back to per-shard commits when alone, un-colocated, or on the
         replay strategy (whose commit is a host-interleaved re-advance)."""
         group = sorted(verified) if self.strategy == "tree" and self._colocated \
@@ -1717,18 +1743,15 @@ class ShardedBatchedSpeculativeEngine:
         fn = self._jit(key, make_group_commit_step(self.shards[0].tc,
                                                    [t for t, _ in sigs]),
                        donate_argnums=0)
-        t0 = time.perf_counter()
-        out = fn(tuple(caches),
-                 tuple(jnp.asarray(t[0]) for t in tables),
-                 tuple(jnp.asarray(t[1]) for t in tables),
-                 tuple(jnp.asarray(t[2]) for t in tables),
-                 tuple(jnp.asarray(t[3]) for t in tables))
+        with span("serve:commit"):
+            out = fn(tuple(caches),
+                     tuple(jnp.asarray(t[0]) for t in tables),
+                     tuple(jnp.asarray(t[1]) for t in tables),
+                     tuple(jnp.asarray(t[2]) for t in tables),
+                     tuple(jnp.asarray(t[3]) for t in tables))
         for si, cache in zip(group, out):
             self.shards[si].tpool.cache = cache
-        if self.profile_commits:
-            jax.block_until_ready(out)
         self._counters["commit_calls"] += 1
-        self._counters["commit_ms"] += (time.perf_counter() - t0) * 1e3
 
     def drain_pipeline(self) -> list[dict]:
         """Drain every shard's begun-ahead step (see
@@ -1799,15 +1822,6 @@ class ShardedBatchedSpeculativeEngine:
         for key in keys:
             if key in self._counters:
                 self._counters[key] = type(self._counters[key])()
-
-    @property
-    def profile_commits(self) -> bool:
-        return self.shards[0].profile_commits
-
-    @profile_commits.setter
-    def profile_commits(self, value: bool) -> None:
-        for sh in self.shards:
-            sh.profile_commits = value
 
     @property
     def queue(self) -> list:

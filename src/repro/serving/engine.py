@@ -33,6 +33,7 @@ from repro.models.cache import fork_streams
 from repro.models.transformer import forward, init_cache
 from repro.sampling import warp_logits
 from repro.serving.serve_step import make_pool_commit_step, next_pow2
+from repro.serving.tracing import jit_named
 
 # top-down OT verifiers with a batched on-device solve (core/otlp_jax.py) —
 # derived from registry metadata, not a hand-maintained name list
@@ -116,13 +117,11 @@ class SpeculativeEngine:
     # ------------------------------------------------------------- helpers ---
 
     def _jit(self, name, fn, donate_argnums=None):
-        """Per-engine jit cache.  ``donate_argnums`` marks pool/cache args
+        """Per-engine jit cache; the program is named after its key
+        (tracing.jit_named).  ``donate_argnums`` marks pool/cache args
         whose buffers XLA may update in place (the commit path donates the
         cache so committing is a lane-move, not a pool copy)."""
-        if name not in self._jit_cache:
-            kw = {} if donate_argnums is None else {"donate_argnums": donate_argnums}
-            self._jit_cache[name] = jax.jit(fn, **kw)
-        return self._jit_cache[name]
+        return jit_named(self._jit_cache, name, fn, donate_argnums)
 
     def jit_compile_count(self) -> int:
         """Compiled signatures across this engine's jit cache — the cold-start

@@ -332,9 +332,8 @@ def make_group_commit_step(cfg, tpads: list[int]):
     commits into ONE jitted dispatch.
 
     The sharded engine's shards each own a private pool, so stepping them
-    as a host loop pays one commit dispatch (and, with ``profile_commits``,
-    one blocking sync) per shard per iteration — the 9 -> 17 ``commit_calls``
-    regression the baselines recorded.  Shard pools are disjoint arrays, so
+    as a host loop pays one commit dispatch per shard per iteration — the
+    9 -> 17 ``commit_calls`` regression the baselines recorded.  Shard pools are disjoint arrays, so
     their commits compose into a single program with no interference: this
     builds one ``make_pool_commit_step`` per shard (each with its own
     ``Tpad`` — shards bucket their speculation shapes independently) and

@@ -215,7 +215,7 @@ def test_one_commit_call_per_step():
         commit_entries = [k for k in beng._jit_cache if k.startswith("commit_")]
         # shape buckets only — independent of how many streams were resident
         assert 1 <= len(commit_entries) <= 3, commit_entries
-        assert beng.counters["commit_ms"] > 0.0
+        assert beng.counters["steps_begun"] == n_steps
 
 
 def test_single_engine_commit_routed_through_primitive():
