@@ -103,7 +103,7 @@ def run_sequential(cfg, tp, dcfg, dp, ecfg, sampling, prompts, max_new, seeds, r
         outs = []
         for p, sd in zip(prompts, seeds):
             eng.rng = np.random.default_rng(sd)
-            outs.append(eng.generate(list(p), max_new=max_new))
+            outs.append(eng.generate(list(p), max_new=max_new, seed=sd))
         return outs
 
     t0 = time.time()

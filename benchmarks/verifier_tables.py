@@ -249,7 +249,7 @@ def exactness_rows(quick: bool, seed: int = 0, max_new: int = 8) -> list[dict]:
             singles = []
             for p, sd in zip(prompts, seeds):
                 seq.rng = np.random.default_rng(sd)
-                singles.append(seq.generate(list(p), max_new=max_new))
+                singles.append(seq.generate(list(p), max_new=max_new, seed=sd))
             outs = beng.generate_batch([list(p) for p in prompts], max_new, seeds=seeds)
             exact = singles == outs
             c = beng.counters

@@ -115,8 +115,10 @@ def timed_passes(eng, prompts, seeds, on_first_step=None):
     rids = [eng.submit(list(p), max_new=MAX_NEW, seed=s) for p, s in zip(prompts, seeds)]
     if on_first_step is not None:
         pending = eng.begin_step()
+        v = eng.verify_step(pending)  # reads the drafted trees back
         on_first_step(pending)
-        eng.finish_step(pending)
+        eng.commit_step(v)
+        eng.retire_step(v)
     outs = eng.run()
     cold = time.perf_counter() - t0
     compile_s = _compile_secs[0] - c0

@@ -70,6 +70,26 @@ def delayed_tree_node_count(K: int, L1: int, L2: int) -> int:
     return 1 + L1 + K * L2
 
 
+def delayed_layout(K: int, L1: int, L2: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(parent, depth, path_id) of a (K, L1, L2) delayed tree in the node
+    order both serving engines draft it: the root, trunk nodes 1..L1, then
+    the branches level by level — node ``1 + L1 + j*K + k`` is branch k's
+    node at depth ``L1 + j + 1``.  The order fixes each node's index, which
+    keys its draft draw (``sampling.draw_keyed``)."""
+    parent, depth, pid = [-1], [0], [0]
+    for i in range(1, L1 + 1):
+        parent.append(i - 1)
+        depth.append(i)
+        pid.append(0)
+    for j in range(L2):
+        for k in range(K):
+            parent.append(L1 if j == 0 else 1 + L1 + (j - 1) * K + k)
+            depth.append(L1 + j + 1)
+            pid.append(k)
+    return (np.asarray(parent, np.int64), np.asarray(depth, np.int64),
+            np.asarray(pid, np.int64))
+
+
 def build_delayed_tree(
     rng: np.random.Generator,
     q_fn,

@@ -235,7 +235,7 @@ def main(argv=None):
         kw["enc_embeds"] = jnp.asarray(rng.standard_normal((1, cfg.enc_len, cfg.d_model)), cfg.jdtype)
     for r in range(args.requests):
         prompt = rng.integers(0, cfg.vocab, size=8).tolist()
-        out = eng.generate(prompt, max_new=args.max_new, **kw)
+        out = eng.generate(prompt, max_new=args.max_new, seed=args.seed + r, **kw)
         print(f"req{r}: {out[:16]}{'...' if len(out) > 16 else ''}")
     dt = time.time() - t0
     c = eng.counters

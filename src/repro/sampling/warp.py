@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def warp_logits(logits: jax.Array, temperature: float = 1.0, top_p: float = 1.0) -> jax.Array:
@@ -49,3 +50,31 @@ def sample_categorical(key: jax.Array, probs: jax.Array) -> jax.Array:
     g = jax.random.gumbel(key, probs.shape, dtype=jnp.float32)
     g = jnp.where(probs > 0, g, -jnp.inf)
     return jnp.argmax(logp + g, axis=-1)
+
+
+def stream_key(seed: int) -> np.ndarray:
+    """A stream's threefry key, ``(2,)`` uint32: its seed's high and low
+    32-bit words, the key ``jax.random.PRNGKey(seed)`` builds from a 64-bit
+    seed.  Seeds are any integer below 2**64 (the benchmark's reach 2**62),
+    so both words count."""
+    s = int(seed) % (1 << 64)
+    return np.asarray([s >> 32, s & 0xFFFFFFFF], np.uint32)
+
+
+def draw_keyed(q: jax.Array, key: jax.Array, C: jax.Array, node: jax.Array) -> jax.Array:
+    """Draw one draft token per row from warped distributions ``q`` (rows, V).
+
+    Row r's key is ``fold_in(fold_in(key[r], C[r]), node[r])``: ``key``
+    (rows, 2) is the row's stream key (``stream_key``), ``C`` the stream's
+    committed length when the step began and ``node`` the drafted node's
+    index in the delayed-tree layout (``core.trees.delayed_layout``).  The
+    draw is Gumbel-max on ``log q`` (``sample_categorical``), with the noise
+    built per row from its own key, so a row's token depends on its key and
+    its q alone — never on which other rows share the call.  Tokens of zero
+    mass are never drawn.  Both engines draw every draft token through this
+    one primitive, which is what keeps their drafts token-identical."""
+
+    def one(q_r, k, c, n):
+        return sample_categorical(jax.random.fold_in(jax.random.fold_in(k, c), n), q_r)
+
+    return jax.vmap(one)(q, key, C, node).astype(jnp.int32)

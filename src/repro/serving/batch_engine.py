@@ -7,9 +7,13 @@ iteration one padded draft-ingest pass, one padded draft step per tree level,
 ONE padded tree-masked target pass, and ONE jitted pool-donating commit —
 with per-stream host verification, so aggregate tokens/sec scales with the
 number of streams while each stream's output remains exactly the warped
-target process.  The commit path is device-resident: host->device traffic
-per step is small index arrays (tokens, parent pointers, accepted-path
-tables) staged in reusable buffers; ancestor masks are composed on device
+target process.  Drafting is device-resident: every draft token is drawn
+on the device from the q its draft program warped, so nothing is read back
+between the draft ingest and the tree pass; drafting's tokens, q's and
+root hidden states are read once, in verification.  The commit path is
+device-resident too: host->device traffic per step is small index arrays
+(parent pointers, node indices, accepted-path tables) staged in reusable
+buffers; ancestor masks are composed on device
 and the ring compaction moves only touched (row, slot) KV lanes
 (serve_step.make_pool_commit_step / kernels/commit_kv.py) instead of
 copying the pool once per stream.
@@ -38,7 +42,13 @@ tests/test_paged_pool.py).  See docs/serving.md for the full lifecycle.
 
 Exactness contract (property-tested in tests/test_batch_engine.py): with the
 same per-stream seed, the batched engine emits token-identical output to an
-independent ``SpeculativeEngine`` run per stream.  This leans on three facts:
+independent ``SpeculativeEngine`` run per stream.  This leans on four facts:
+
+  * both engines draw every draft token through one keyed primitive
+    (``sampling.draw_keyed``: Gumbel-max on the float32 q the verifier
+    reads, keyed by the stream's seed, its committed length and the node's
+    index, with noise built per row), and spend the seed's numpy rng on
+    verification alone — so a row's draws depend on its key and q only;
 
   * attention/MoE/MLP compute is per-row and per-query: padding extra rows
     (idle slots) or extra query tokens (masked via ``lens`` / the ancestor
@@ -86,7 +96,10 @@ retiring stream's slot/block release lands BEFORE the begun-ahead boundary
 (the boundary sees exactly the post-release pool a synchronous
 ``begin_step`` would), and a begun step can be drained (``drain_pipeline``)
 or rewound (``abort_step``, ``abort_pipeline``) when out-of-band events —
-a mid-run ``submit`` against a free row — would have changed it.  The rewind is LOGICAL for attention-family draft pools —
+a mid-run ``submit`` against a free row — would have changed it.  A
+re-begun step draws the same draft tokens from the same keys, and no
+verification has spent a begun step's rngs, so the rewind restores pool
+state only.  It is LOGICAL for attention-family draft pools —
 ingest writes are append-only and deterministic, so ``invalidate_from``
 erases them and the re-begun step re-ingests identical lanes — and only
 recurrent draft pools hold the double-buffered back frame (models/cache.py
@@ -106,7 +119,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.traversal import delayed_structure
-from repro.core.trees import DraftTree
+from repro.core.trees import DraftTree, delayed_layout
 from repro.core.verify import get_verifier
 from repro.launch.mesh import shard_meshes
 from repro.launch.sharding import pad_slots, pool_shardings
@@ -119,28 +132,35 @@ from repro.models.cache import (
     scatter_streams,
 )
 from repro.models.transformer import forward, init_cache
-from repro.sampling import warp_logits
+from repro.sampling import stream_key, warp_logits
 from repro.serving.engine import (
     EngineConfig,
     SamplingParams,
     SpeculativeEngine,
-    draw_token,
     to_verifier_dtype,
     verify_tree,
 )
 from repro.serving.serve_step import (
     StagingBuffers,
+    make_draft_draw_step,
     make_group_commit_step,
     make_pool_commit_step,
     make_pool_decode_step,
-    make_pool_locked_step,
     make_pool_ragged_tree_step,
     make_pool_tree_step,
     next_pow2 as _next_pow2,
+    take_rows,
 )
 from repro.serving.tracing import jit_named, span, step_span, traced
 
 RECURRENT = ("ssm", "hybrid")
+
+
+def _ragged_tokens(tok_nodes, at):
+    """The ragged tree pass's (Npad,) tokens: lanes ``at`` of drafting's
+    (n_slots, Tpad) node tokens, flattened; ``at = n_slots * Tpad`` reads
+    token 0 (alignment-gap and padding lanes)."""
+    return take_rows((tok_nodes.reshape(-1),), at)
 
 
 @dataclass
@@ -163,11 +183,19 @@ class PendingStep:
     against.  The replay strategy's target pass is host-interleaved, so it
     arrives already materialised as ``snapshot``/``p_host``.
 
-    ``C0`` (committed length minus the pending root, per slot), ``D0``
+    Drafting's outputs are device arrays too, with async host copies kicked
+    off at dispatch: ``tok_dev`` (n_slots, Tpad) drafted tokens in node
+    order (root = the pending token; None on the replay strategy, which
+    reads them before its target pass), ``q_dev`` (n_slots, Tpad, V) the
+    nodes' warped draft distributions and ``hq_dev`` (n_slots, d) the draft
+    hidden state at each root.  ``trees`` holds each stream's tree layout
+    from ``begin_step`` on; ``verify_step`` reads the three arrays once and
+    completes the trees' tokens and q, and ``hq``.
+
+    ``C0`` (committed length minus the pending root, per slot) and ``D0``
     (the draft pool's pre-ingest length, per slot — attention-family draft
-    pools rewind logically instead of holding a back frame) and
-    ``rng_state`` (per-stream generator snapshots, pipelined mode only)
-    are the rewind coordinates ``abort_step`` uses."""
+    pools rewind logically instead of holding a back frame; pipelined mode
+    only) are the rewind coordinates ``abort_step`` uses."""
 
     active: list[int]
     acts: dict[int, tuple]
@@ -179,8 +207,10 @@ class PendingStep:
     hid_dev: object = None
     snapshot: dict | None = None
     p_host: dict | None = None
-    rng_state: dict | None = None
     D0: dict[int, int] | None = None
+    tok_dev: object = None
+    q_dev: object = None
+    hq_dev: object = None
     # tree strategy, ragged layout only: ({slot: (offset, n_nodes)}, Npad)
     # — how the flat node-major logits/hidden buffers slice back into
     # per-stream trees (None = padded (B, Tpad) layout)
@@ -323,7 +353,10 @@ class BatchedSpeculativeEngine:
         # it under the heterogeneous scenario).  tree_calls_padded /
         # tree_calls_ragged split target_calls by tree-pass layout.
         # readback_bytes: device->host bytes of every blocking read
-        # (``_fetch``).  steps_begun / steps_rewound / steps_drained: begun
+        # (``_fetch``); draft_syncs: those made while a step drafts, from
+        # the draft ingest to the tree dispatch (0 on the tree strategy:
+        # draft tokens are drawn on the device).  steps_begun /
+        # steps_rewound / steps_drained: begun
         # steps, and those a mid-run submit() rewound (abort_step) or
         # finished early; once nothing is pending, steps_begun == finished
         # steps + steps_rewound.  admitted / queue_ms: requests admitted and
@@ -335,9 +368,10 @@ class BatchedSpeculativeEngine:
                          "blocks_reclaimed": 0, "admit_blocked": 0, "blocks_peak": 0,
                          "pad_nodes_total": 0, "tree_lanes_total": 0,
                          "pipeline_ahead": 0, "pipeline_stalls": 0,
-                         "pipeline_iterations": 0, "readback_bytes": 0,
+                         "pipeline_iterations": 0, "readback_bytes": 0, "draft_syncs": 0,
                          "steps_begun": 0, "steps_rewound": 0, "steps_drained": 0,
                          "admitted": 0, "queue_ms": 0.0}
+        self._drafting = False   # between the draft ingest and the tree dispatch
 
     def reset_counters(self, keys) -> None:
         """Zero the named counters (shared surface with the sharded engine —
@@ -374,6 +408,8 @@ class BatchedSpeculativeEngine:
         with span(f"serve:wait.{what}"):
             a = np.asarray(x)
         self.counters["readback_bytes"] += a.nbytes
+        if self._drafting:
+            self.counters["draft_syncs"] += 1
         return a
 
     def jit_compile_count(self) -> int:
@@ -579,7 +615,8 @@ class BatchedSpeculativeEngine:
                 "rid": req.rid,
                 "slot": slot,
                 "seq": self._admit_seq,
-                "rng": np.random.default_rng(req.seed),
+                "rng": np.random.default_rng(req.seed),  # spent by verification
+                "key": stream_key(req.seed),              # keys the draft draws
                 "max_new": req.max_new,
                 "out": [],
                 "committed": list(req.prompt),
@@ -608,13 +645,18 @@ class BatchedSpeculativeEngine:
     @traced("serve:ingest")
     def _ingest_deltas(self, active):
         """Advance the draft pool over each stream's newly committed tokens.
-        Returns per-slot (q0 dist, draft hidden at the new root)."""
-        q0, hq = {}, {}
+        Returns DEVICE arrays: every row's warped q0 (n_slots, V) and draft
+        hidden state (n_slots, d) at its last ingested position, taken and
+        warped on the device — no host read."""
+        t, p = self.sampling.temperature, self.sampling.top_p
+        warp = self._jit("drf_ing_q", partial(warp_logits, temperature=t, top_p=p))
         if self._recurrent(self.dc):
             groups = defaultdict(list)
             for s in active:
                 groups[len(self.streams[s]["draft_delta"])].append(s)
-            trims, all_rows = [], []
+            trims, all_rows, lasts, hs = [], [], [], []
+            at = self._stage("ing_rows", (self.n_slots,), np.int32,
+                             fill=len(groups) * self.n_slots)
             for L, rows in sorted(groups.items()):
                 toks = np.asarray([self.streams[s]["draft_delta"] for s in rows], np.int32)
                 rows_p, toks_p = self._pad_group(rows, toks, self.n_slots)
@@ -622,38 +664,49 @@ class BatchedSpeculativeEngine:
                 fn = self._jit(f"drf_ing_g{L}", partial(forward, cfg=self.dc, mode="decode"))
                 logits, sub, ex = fn(self.dp, tokens=jnp.asarray(toks_p), cache=sub)
                 trims.append(gather_streams(sub, list(range(len(rows)))))
-                all_rows.extend(rows)
-                w = self._fetch(self._warp(logits), "ingest")
-                hid = self._fetch(ex["hidden"], "ingest")
                 for i, s in enumerate(rows):
-                    q0[s] = w[i, L - 1]
-                    hq[s] = hid[i, L - 1]
+                    at[s] = len(lasts) * self.n_slots + i
+                lasts.append(logits[:, L - 1])
+                hs.append(ex["hidden"][:, L - 1])
+                all_rows.extend(rows)
                 self.counters["draft_calls"] += 1
                 self.counters["draft_tokens"] += L * len(rows)
             # one write-back for every length group's rows — donated unless
             # the pipelined back frame still aliases the pre-step buffer
             self.dpool.cache = self._scatter_rows(self.dpool.cache, trims, all_rows,
                                                   donate=not self.dpool.frame_held)
-        else:
-            Dp = _next_pow2(max(len(self.streams[s]["draft_delta"]) for s in active))
-            toks = self._stage("ing_toks", (self.n_slots, Dp), np.int32)
-            lens = self._stage("ing_lens", (self.n_slots,), np.int32)
-            for s in active:
-                d = self.streams[s]["draft_delta"]
-                toks[s, : len(d)] = d
-                lens[s] = len(d)
-            fn = self._jit(f"drf_ing_p{Dp}", make_pool_decode_step(self.dc))
-            logits, cache, hidden = fn(self.dp, self.dpool.cache, jnp.asarray(toks),
-                                       jnp.asarray(lens))
-            self.dpool.cache = cache
-            w = self._fetch(self._warp(logits), "ingest")
-            hid = self._fetch(hidden, "ingest")
-            for s in active:
-                q0[s] = w[s, lens[s] - 1]
-                hq[s] = hid[s, lens[s] - 1]
-            self.counters["draft_calls"] += 1
-            self.counters["draft_tokens"] += int(lens.sum())
-        return q0, hq
+            gather = self._jit("drf_ing_rows", take_rows)
+            at = jnp.asarray(at)
+            return warp(gather(tuple(lasts), at)), gather(tuple(hs), at)
+        Dp = _next_pow2(max(len(self.streams[s]["draft_delta"]) for s in active))
+        toks = self._stage("ing_toks", (self.n_slots, Dp), np.int32)
+        lens = self._stage("ing_lens", (self.n_slots,), np.int32)
+        for s in active:
+            d = self.streams[s]["draft_delta"]
+            toks[s, : len(d)] = d
+            lens[s] = len(d)
+        fn = self._jit(f"drf_ing_p{Dp}", make_pool_decode_step(self.dc))
+        logits, cache, hidden = fn(self.dp, self.dpool.cache, jnp.asarray(toks),
+                                   jnp.asarray(lens))
+        self.dpool.cache = cache
+        self.counters["draft_calls"] += 1
+        self.counters["draft_tokens"] += int(lens.sum())
+        return warp(logits), hidden
+
+    def _warm_ragged_tokens(self) -> None:
+        """Compile, without running it, the ragged tree pass's token gather
+        for every Npad bucket the default action's steps can reach (below
+        the padded lane count): the first begin_step does this, which a
+        server's warm-up steps reach; a later bucket would otherwise compile
+        mid-serving."""
+        e = self.ecfg
+        *_, Tpad = self._bucket_actions({0: (e.K, e.L1, e.L2)})
+        gather = jit_named(self._jit_cache, "drf_step_rtoks", _ragged_tokens)
+        N = _next_pow2(max(Tpad, self._ragged_align))
+        while N < self.n_slots * Tpad:
+            gather.lower(jax.ShapeDtypeStruct((self.n_slots, Tpad), jnp.int32),
+                         jax.ShapeDtypeStruct((N,), jnp.int32)).compile()
+            N *= 2
 
     @staticmethod
     def _bucket_actions(acts) -> tuple[int, int, int, int]:
@@ -741,125 +794,133 @@ class BatchedSpeculativeEngine:
     @traced("serve:draft")
     def _draft_trees(self, active, acts, q0, pads):
         """Lockstep-draft every stream's (K, L1, L2) delayed tree on a local
-        copy of the draft pool (discarded after, like the single engine)."""
+        copy of the draft pool (discarded after, like the single engine),
+        with every token drawn on the device (serve_step.make_draft_draw_step:
+        the trunk feeds its token into the next trunk level, the fork into
+        the branch levels) — no host read between the levels.
+
+        Returns ``(trees, tok_dev, q_dev)``: each stream's tree layout
+        (``core.trees.delayed_layout``; tokens and q filled in by
+        ``verify_step``), and the drafted tokens (n_slots, Tpad) and node
+        q's (n_slots, Tpad, V) gathered into node order on the device, with
+        their host copies started."""
         Kp, L1p, L2p, Tpad = pads
         # loop trip counts are host-side, not compiled shapes: iterate to the
         # raw batch maxima (the bucketed L1p/L2p only size the tree pass)
         L1m = max(a[1] for a in acts.values())
-        L2m = max(a[2] for a in acts.values())
+        L2m = max(a[2] for a in acts.values()) if Kp and L2p else 0
+        B = self.n_slots
+        t, p = self.sampling.temperature, self.sampling.top_p
+        key = self._stage("draft_key", (B, 2), np.uint32)
+        C = self._stage("draft_C", (B,), np.int32)
+        pend = self._stage("draft_pending", (B,), np.int32)
+        for s in active:
+            st = self.streams[s]
+            key[s] = st["key"]
+            C[s] = len(st["committed"])
+            pend[s] = st["pending"]
+        key_d, C_d = jnp.asarray(key), jnp.asarray(C)
+        # per-level device outputs, gathered into node order at the end:
+        # level 0 is the roots (pending token, q0), then one level per trunk
+        # step (B rows) and per branch step (B * Kp rows)
+        qs, toks = [q0], [jnp.asarray(pend)]
         dwork = self.dpool.cache
-        cur = dict(q0)
-        trunk_tok = {s: [] for s in active}
-        trunk_q = {s: [] for s in active}
-        step_fn = self._jit("drf_step", make_pool_locked_step(self.dc))
+        q = q0
+        step_fn = self._jit("drf_step_draw", make_draft_draw_step(self.dc, t, p))
         for j in range(L1m):
-            toks = np.zeros((self.n_slots, 1), np.int32)
-            keep = np.zeros((self.n_slots,), bool)
-            n_live = 0
+            keep = np.zeros((B,), bool)
             for s in active:
-                if j < acts[s][1]:
-                    t = draw_token(self.streams[s]["rng"], cur[s])
-                    toks[s, 0] = t
-                    keep[s] = True
-                    trunk_tok[s].append(t)
-                    n_live += 1
-            logits, dwork = step_fn(self.dp, dwork, jnp.asarray(toks), jnp.asarray(keep))
-            w = self._fetch(self._warp(logits[:, 0]), "draft")
-            for s in active:
-                if keep[s]:
-                    cur[s] = w[s]
-                    trunk_q[s].append(w[s])
+                keep[s] = j < acts[s][1]
+            tok, q, dwork = step_fn(self.dp, dwork, q, key_d, C_d,
+                                    np.full((B,), j + 1, np.int32), keep)
+            qs.append(q)
+            toks.append(tok)
             self.counters["draft_calls"] += 1
-            self.counters["draft_tokens"] += n_live
-
-        branch_tok = {s: [[] for _ in range(acts[s][0])] for s in active}
-        branch_q = {s: [[] for _ in range(acts[s][0])] for s in active}
-        if Kp and L2p:
+            self.counters["draft_tokens"] += int(keep.sum())
+        if L2m:
             dfork = fork_streams(dwork, Kp)
-            V = self.tc.vocab
-            curb = np.zeros((self.n_slots * Kp, V), np.float32)
-            for s in active:
-                for k in range(acts[s][0]):
-                    curb[s * Kp + k] = cur[s]
-            bstep = self._jit(f"drf_bstep_k{Kp}", partial(forward, cfg=self.dc, mode="decode"))
+            fork_rows = np.repeat(np.arange(B, dtype=np.int32), Kp)
+            q = self._jit("drf_bstep_fork", take_rows)((q,), fork_rows)
+            bkey, bC = jnp.asarray(key[fork_rows]), jnp.asarray(C[fork_rows])
+            bstep = self._jit(f"drf_bstep_draw_k{Kp}", make_draft_draw_step(self.dc, t, p))
             for j in range(L2m):
-                toks = np.zeros((self.n_slots * Kp, 1), np.int32)
+                node = np.zeros((B * Kp,), np.int32)
                 n_live = 0
                 for s in active:
-                    K, _, L2 = acts[s]
+                    K, L1, L2 = acts[s]
                     if j < L2:
-                        for k in range(K):
-                            t = draw_token(self.streams[s]["rng"], curb[s * Kp + k])
-                            toks[s * Kp + k, 0] = t
-                            branch_tok[s][k].append(t)
-                            n_live += 1
-                logits, dfork, _ = bstep(self.dp, tokens=jnp.asarray(toks), cache=dfork)
-                w = self._fetch(self._warp(logits[:, 0]), "draft")
-                for s in active:
-                    K, _, L2 = acts[s]
-                    if j < L2:
-                        for k in range(K):
-                            curb[s * Kp + k] = w[s * Kp + k]
-                            branch_q[s][k].append(w[s * Kp + k])
+                        node[s * Kp: s * Kp + K] = 1 + L1 + j * K + np.arange(K)
+                        n_live += K
+                tok, q, dfork = bstep(self.dp, dfork, q, bkey, bC, node)
+                qs.append(q)
+                toks.append(tok)
                 self.counters["draft_calls"] += 1
                 self.counters["draft_tokens"] += n_live
-
+        # node (s, i) -> its row in the concatenated levels; padding nodes
+        # and idle rows read the zero row past the end (token 0)
+        branch0 = B * (1 + L1m)
+        idx = self._stage("draft_nodes", (B, Tpad), np.int32,
+                          fill=branch0 + L2m * B * Kp)
         trees = {}
         for s in active:
             K, L1, L2 = acts[s]
-            tokens, parent, depth, pid, qs = [-1], [-1], [0], [0], [q0[s]]
-            node = 0
-            for j in range(L1):
-                tokens.append(trunk_tok[s][j])
-                parent.append(node)
-                depth.append(depth[node] + 1)
-                pid.append(0)
-                qs.append(trunk_q[s][j])
-                node = len(tokens) - 1
-            branch_nodes = [node] * K
+            idx[s, 0] = s
+            idx[s, 1:1 + L1] = B * np.arange(1, L1 + 1) + s
             for j in range(L2):
-                for k in range(K):
-                    tokens.append(branch_tok[s][k][j])
-                    parent.append(branch_nodes[k])
-                    depth.append(depth[branch_nodes[k]] + 1)
-                    pid.append(k)
-                    qs.append(branch_q[s][k][j])
-                    branch_nodes[k] = len(tokens) - 1
-            trees[s] = DraftTree(
-                tokens=np.asarray(tokens, np.int64),
-                parent=np.asarray(parent, np.int64),
-                depth=np.asarray(depth, np.int64),
-                q=np.stack(qs),
-                path_id=np.asarray(pid, np.int64),
-            )
-        return trees
+                idx[s, 1 + L1 + j * K: 1 + L1 + (j + 1) * K] = (
+                    branch0 + j * B * Kp + s * Kp + np.arange(K))
+            parent, depth, pid = delayed_layout(K, L1, L2)
+            trees[s] = DraftTree(tokens=np.full(len(parent), -1, np.int64), parent=parent,
+                                 depth=depth, q=None, path_id=pid)
+        nodes = self._jit("drf_step_tree", lambda qs, ts, at: (take_rows(qs, at),
+                                                               take_rows(ts, at)))
+        q_dev, tok_dev = nodes(tuple(qs), tuple(toks), jnp.asarray(idx))
+        for arr in (tok_dev, q_dev):
+            arr.copy_to_host_async()
+        return trees, tok_dev, q_dev
+
+    def _fill_tokens(self, trees, tok_dev) -> None:
+        """Read the drafted tokens (one small read) into the trees."""
+        toks = self._fetch(tok_dev, "draft")
+        for s, tree in trees.items():
+            tree.tokens[1:] = toks[s, 1:tree.n_nodes]
+
+    def _read_drafts(self, pending: PendingStep) -> None:
+        """The step's one deferred read of drafting's outputs: complete each
+        stream's tree (tokens, q) and its root draft hidden state ``hq``."""
+        if pending.tok_dev is not None:
+            self._fill_tokens(pending.trees, pending.tok_dev)
+        q = self._fetch(pending.q_dev, "draft")
+        hid = self._fetch(pending.hq_dev, "draft")
+        for s in pending.active:
+            tree = pending.trees[s]
+            tree.q = q[s, :tree.n_nodes]
+            pending.hq[s] = hid[s]
 
     # ----------------------------------------------------- target: tree -----
 
     @traced("serve:dispatch")
-    def _target_tree_dispatch(self, active, trees, Tpad):
+    def _target_tree_dispatch(self, active, trees, Tpad, tok_dev):
         """Dispatch ONE padded tree-masked target pass over every active row
         and return its warped logits / hidden states as DEVICE arrays (with
         async host copies kicked off) — the futures ``finish_step`` blocks
         on, so the host is free between dispatch and verification.
 
-        The host ships (B, Tpad) token and parent-pointer index arrays only:
+        The tokens are drafting's device array ``tok_dev`` (n_slots, Tpad),
+        the pending token at each root; the host ships (B, Tpad)
+        parent-pointer index arrays only, which depend on the actions alone:
         ancestor masks are composed on device (device_ancestor_mask) and the
         idle-row freeze happens inside the same jit call — no per-iteration
         (B, Tpad, Tpad) mask tensor is rebuilt or transferred."""
-        ttoks = self._stage("tree_toks", (self.n_slots, Tpad), np.int32)
         parents = self._stage("tree_parents", (self.n_slots, Tpad), np.int32, fill=-1)
         keep = self._stage("tree_keep", (self.n_slots,), np.bool_, fill=False)
         for s in active:
             tree = trees[s]
-            n = tree.n_nodes
-            ttoks[s, :n] = tree.tokens
-            ttoks[s, 0] = self.streams[s]["pending"]
-            parents[s, :n] = tree.parent
+            parents[s, :tree.n_nodes] = tree.parent
             keep[s] = True
         fn = self._jit(f"tgt_tree_p{Tpad}", make_pool_tree_step(self.tc),
                        donate_argnums=1)
-        logits, cache, hidden = fn(self.tp, self.tpool.cache, jnp.asarray(ttoks),
+        logits, cache, hidden = fn(self.tp, self.tpool.cache, tok_dev,
                                    jnp.asarray(parents), jnp.asarray(keep))
         self.tpool.cache = cache
         real = sum(trees[s].n_nodes for s in active)
@@ -888,16 +949,18 @@ class BatchedSpeculativeEngine:
         return offs, _next_pow2(max(off, align))
 
     @traced("serve:dispatch")
-    def _target_tree_dispatch_ragged(self, active, trees, roffs):
+    def _target_tree_dispatch_ragged(self, active, trees, roffs, tok_dev):
         """Ragged counterpart of ``_target_tree_dispatch``: ONE flat
         node-major tree pass over every active stream's tree, no per-row
         padding to the pool-wide Tpad (serve_step.make_pool_ragged_tree_step;
-        docs/serving.md "Ragged node-major tree batching").  The host ships
-        (Npad,) token/owner/parent/depth/local arrays plus (B,) counts —
-        the same small-index-arrays contract as the padded dispatch, with
-        identical async-host-copy futures returned."""
+        docs/serving.md "Ragged node-major tree batching").  The (Npad,)
+        tokens are gathered on the device from drafting's (n_slots, Tpad)
+        ``tok_dev``; the host ships (Npad,) owner/parent/depth/local arrays
+        plus (B,) counts — the same small-index-arrays contract as the
+        padded dispatch, with identical async-host-copy futures returned."""
         offs, Npad = roffs
-        toks = self._stage("rtree_toks", (Npad,), np.int32)
+        Tpad = tok_dev.shape[1]
+        at = self._stage("rtree_at", (Npad,), np.int32, fill=self.n_slots * Tpad)
         owner = self._stage("rtree_owner", (Npad,), np.int32)
         parent = self._stage("rtree_parent", (Npad,), np.int32, fill=-1)
         depth = self._stage("rtree_depth", (Npad,), np.int32)
@@ -907,8 +970,7 @@ class BatchedSpeculativeEngine:
         for s in active:
             o, n = offs[s]
             tree = trees[s]
-            toks[o:o + n] = tree.tokens
-            toks[o] = self.streams[s]["pending"]
+            at[o:o + n] = s * Tpad + np.arange(n)
             parent[o:o + n] = np.where(tree.parent >= 0, o + tree.parent, -1)
             depth[o:o + n] = tree.depth
             local[o:o + n] = np.arange(n)
@@ -917,9 +979,10 @@ class BatchedSpeculativeEngine:
             # the segment's owner so pallas Q tiles stay owner-uniform
             owner[o:o + (-(-n // align) * align)] = s
             counts[s] = n
+        toks = self._jit("drf_step_rtoks", _ragged_tokens)(tok_dev, jnp.asarray(at))
         fn = self._jit(f"tgt_rtree_n{Npad}", make_pool_ragged_tree_step(self.tc),
                        donate_argnums=1)
-        logits, cache, hidden = fn(self.tp, self.tpool.cache, jnp.asarray(toks),
+        logits, cache, hidden = fn(self.tp, self.tpool.cache, toks,
                                    jnp.asarray(owner), jnp.asarray(parent),
                                    jnp.asarray(depth), jnp.asarray(local),
                                    jnp.asarray(counts))
@@ -977,7 +1040,7 @@ class BatchedSpeculativeEngine:
         (n_nodes, vocab) allocations per stream per step."""
         snapshot = self.tpool.cache
         structs = {s: delayed_structure(trees[s]) for s in active}
-        p_host = {s: np.zeros((trees[s].n_nodes, trees[s].vocab), np.float32)
+        p_host = {s: np.zeros((trees[s].n_nodes, self.tc.vocab), np.float32)
                   for s in active}
         groups = defaultdict(list)
         for s in active:
@@ -1124,15 +1187,14 @@ class BatchedSpeculativeEngine:
                     return None
                 pads = self._bucket_actions(acts)
                 Kp, L1p, L2p, Tpad = pads
-        # rewind coordinates (pipelined mode): abort_step can restore
-        # rng/draft state as if the step never began
+        # rewind coordinates (pipelined mode): abort_step can restore the
+        # draft pool as if the step never began.  No rng needs saving: the
+        # draft draws are keyed (seed, committed length, node), so a rewound
+        # step begun again draws the same tokens, and the streams' rngs are
+        # spent by verification alone, which no begun step has run
         C0 = {s: len(self.streams[s]["committed"]) - 1 for s in active}
-        rng_state, D0 = None, None
+        D0 = None
         if self.pipeline:
-            # numpy's .state property builds a fresh dict per access, so the
-            # snapshot needs no deepcopy
-            rng_state = {s: self.streams[s]["rng"].bit_generator.state
-                         for s in active}
             if self._recurrent(self.dc):
                 # recurrent draft state integrates every token — it can only
                 # be rewound from a saved copy, so hold the back frame
@@ -1148,9 +1210,13 @@ class BatchedSpeculativeEngine:
                 D0 = {s: len(self.streams[s]["committed"])
                          - len(self.streams[s]["draft_delta"])
                       for s in active}
+        if self._ragged_ok and "drf_step_rtoks" not in self._jit_cache:
+            self._warm_ragged_tokens()
         self.counters["steps_begun"] += 1
-        q0, hq = self._ingest_deltas(active)
-        trees = self._draft_trees(active, acts, q0, pads)
+        self._drafting = True
+        q0, hq_dev = self._ingest_deltas(active)
+        trees, tok_dev, q_dev = self._draft_trees(active, acts, q0, pads)
+        hq_dev.copy_to_host_async()
         if self.strategy == "tree":
             roffs = None
             if self._ragged_ok:
@@ -1162,17 +1228,22 @@ class BatchedSpeculativeEngine:
                     roffs = (offs, Npad)
             if roffs is not None:
                 p_dev, hid_dev = self._target_tree_dispatch_ragged(
-                    active, trees, roffs)
+                    active, trees, roffs, tok_dev)
             else:
-                p_dev, hid_dev = self._target_tree_dispatch(active, trees, Tpad)
+                p_dev, hid_dev = self._target_tree_dispatch(active, trees, Tpad, tok_dev)
+            self._drafting = False
             return PendingStep(active=active, acts=acts, pads=pads, trees=trees,
-                               hq=hq, C0=C0, p_dev=p_dev, hid_dev=hid_dev,
-                               rng_state=rng_state, D0=D0, roffs=roffs,
-                               boundary_evicted=boundary_evicted)
+                               hq={}, C0=C0, p_dev=p_dev, hid_dev=hid_dev, D0=D0,
+                               tok_dev=tok_dev, q_dev=q_dev, hq_dev=hq_dev,
+                               roffs=roffs, boundary_evicted=boundary_evicted)
+        # the replay target pass is host-interleaved: it reads the small
+        # token array once, before its first call
+        self._fill_tokens(trees, tok_dev)
+        self._drafting = False
         snapshot, p_host = self._target_replay(active, trees, acts, Kp)
         return PendingStep(active=active, acts=acts, pads=pads, trees=trees,
-                           hq=hq, C0=C0, snapshot=snapshot, p_host=p_host,
-                           rng_state=rng_state, D0=D0,
+                           hq={}, C0=C0, snapshot=snapshot, p_host=p_host, D0=D0,
+                           q_dev=q_dev, hq_dev=hq_dev,
                            boundary_evicted=boundary_evicted)
 
     @traced("serve:verify")
@@ -1185,6 +1256,7 @@ class BatchedSpeculativeEngine:
         in flight, then batch all commits into one call."""
         if self.dpool.frame_held:
             self.dpool.drop_frame()  # committing to this step: no rewind past here
+        self._read_drafts(pending)
         active, trees = pending.active, pending.trees
         accepted, corr = {}, {}
         if self.strategy == "tree":
@@ -1340,31 +1412,30 @@ class BatchedSpeculativeEngine:
     @traced("serve:abort")
     def abort_step(self, pending: PendingStep) -> None:
         """Rewind a begun step as if it never dispatched (pipelined mode):
-        restore every active stream's rng snapshot, rewind the draft pool —
-        logically for attention-family drafts (the step's only draft-pool
-        mutation is the append-only delta ingest: erase pos >= D0 lanes
-        with ``invalidate_from`` and the re-begun step re-ingests identical
-        values), from the double-buffered back frame for recurrent drafts —
-        and invalidate the target rows' speculative tree writes (their pool
-        buffer was donated, so the pre-pass buffer is gone — but every
-        speculative lane carries pos >= C0 and is erased by
-        ``CachePool.invalidate_from``; the replay strategy never touches the
-        target pool before its commit).  Boundary decisions taken by
-        ``begin_step`` (admissions, evictions, block mappings) are
-        scheduling events that stand; dead mappings are recycled by the
-        normal pressure path.  Work counters also stand — they count
-        dispatched work — and ``steps_rewound`` counts the rewind."""
-        assert pending.rng_state is not None, \
-            "abort_step needs the rng snapshots only pipelined begin_step records"
+        rewind the draft pool — logically for attention-family drafts (the
+        step's only draft-pool mutation is the append-only delta ingest:
+        erase pos >= D0 lanes with ``invalidate_from`` and the re-begun step
+        re-ingests identical values), from the double-buffered back frame
+        for recurrent drafts — and invalidate the target rows' speculative
+        tree writes (their pool buffer was donated, so the pre-pass buffer
+        is gone — but every speculative lane carries pos >= C0 and is erased
+        by ``CachePool.invalidate_from``; the replay strategy never touches
+        the target pool before its commit).  Nothing else needs restoring:
+        the re-begun step draws the same draft tokens from the same keys,
+        and the streams' rngs are spent only by verification, which a begun
+        step has not run.  Boundary decisions taken by ``begin_step``
+        (admissions, evictions, block mappings) are scheduling events that
+        stand; dead mappings are recycled by the normal pressure path.  Work
+        counters also stand — they count dispatched work — and
+        ``steps_rewound`` counts the rewind."""
+        assert pending.D0 is not None or self.dpool.frame_held, \
+            "abort_step rewinds only a step a pipelined begin_step began"
         self.counters["steps_rewound"] += 1
         if pending is self._pending_next:
             self._pending_next = None
-        for s, state in pending.rng_state.items():
-            if s in self.streams:
-                self.streams[s]["rng"].bit_generator.state = state
         if self.dpool.frame_held:
             self.dpool.rollback_frame()
-        elif pending.D0 is not None:
+        else:
             self.dpool.invalidate_from({s: pending.D0[s] for s in pending.active
                                         if s in self.streams})
         if self.strategy == "tree":
@@ -1762,11 +1833,11 @@ class ShardedBatchedSpeculativeEngine:
         return events
 
     def abort_pipeline(self) -> int:
-        """Rewind EVERY shard's begun-ahead step (each shard restores its
-        own rng snapshots and pool state — ``abort_step``).  Returns how
-        many shards rewound a step; with several shards begun ahead all of
-        them must land, or the next boundary would replay some shards'
-        randomness against others' already-consumed state."""
+        """Rewind EVERY shard's begun-ahead step (each shard rewinds its
+        own pool state — ``abort_step``).  Returns how many shards rewound a
+        step; with several shards begun ahead all of them must land, or a
+        shard's dispatched step would stand through the out-of-band change
+        the rewind was for."""
         return sum(sh.abort_pipeline() for sh in self.shards)
 
     def run(self) -> dict[int, dict]:

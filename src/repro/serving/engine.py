@@ -27,11 +27,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.trees import DraftTree, tree_ancestor_mask
+from repro.core.trees import DraftTree, delayed_layout, tree_ancestor_mask
 from repro.core.verify import VERIFIERS, get_verifier
 from repro.models.cache import fork_streams
 from repro.models.transformer import forward, init_cache
-from repro.sampling import warp_logits
+from repro.sampling import draw_keyed, stream_key, warp_logits
 from repro.serving.serve_step import make_pool_commit_step, next_pow2
 from repro.serving.tracing import jit_named
 
@@ -51,15 +51,6 @@ def to_verifier_dtype(p: np.ndarray) -> np.ndarray:
     the replay path once hand-rolled its own and drifted (PR-2 notes), which
     a future dtype change would silently repeat."""
     return np.asarray(p, VERIFIER_DTYPE)
-
-
-def draw_token(rng: np.random.Generator, dist: np.ndarray) -> int:
-    """Sample one token from a warped distribution.
-
-    The single draw primitive both engines share: batch-vs-single exactness
-    requires identical rng consumption, so neither engine may inline its own
-    variant of this."""
-    return int(rng.choice(len(dist), p=dist / dist.sum()))
 
 
 def verify_tree(tree: DraftTree, verifier: str, rng: np.random.Generator):
@@ -132,16 +123,16 @@ class SpeculativeEngine:
     def _warp(self, logits):
         return warp_logits(logits, self.sampling.temperature, self.sampling.top_p)
 
+    def _draft_fn(self, T: int):
+        """The draft's decode program over T tokens (jit-cached per T)."""
+        return self._jit(f"draft_dec_{T}", partial(forward, cfg=self.dc, mode="decode"))
+
     def _draft_decode(self, cache, tokens_np):
         """Run the draft model over T committed/drafted tokens. Returns
         (warped dists (T, V) np, new cache, hidden (T, D))."""
         T = len(tokens_np)
-        fn = self._jit(
-            f"draft_dec_{T}",
-            partial(forward, cfg=self.dc, mode="decode"),
-        )
         toks = jnp.asarray(np.asarray(tokens_np, np.int32)[None])
-        logits, cache, ex = fn(self.dp, tokens=toks, cache=cache)
+        logits, cache, ex = self._draft_fn(T)(self.dp, tokens=toks, cache=cache)
         self.counters["draft_calls"] += 1
         self.counters["draft_tokens"] += T
         return np.asarray(self._warp(logits[0])), cache, np.asarray(ex["hidden"][0])
@@ -172,8 +163,15 @@ class SpeculativeEngine:
 
     # -------------------------------------------------------------- stream ---
 
-    def new_stream(self, prompt: list[int], enc_embeds=None, embeds=None) -> dict:
-        """Prefill prompt[:-1] into both caches; prompt[-1] is the pending root."""
+    def new_stream(self, prompt: list[int], enc_embeds=None, embeds=None,
+                   seed: int | None = None) -> dict:
+        """Prefill prompt[:-1] into both caches; prompt[-1] is the pending root.
+
+        ``seed`` (default: the engine's ``EngineConfig.seed``) keys the
+        stream's draft draws, as a request's seed does in the batched
+        engine; the engine's rng is spent by verification.  Streams of one
+        engine that share a seed share their draft draws at equal committed
+        lengths, so give each stream its own."""
         assert len(prompt) >= 1
         tcache = init_cache(self.tc, 1, self.ecfg.max_cache)
         dcache = init_cache(self.dc, 1, self.ecfg.max_cache)
@@ -212,66 +210,71 @@ class SpeculativeEngine:
             "p_prev": None,
             "q_prev": None,
             "done": False,
+            "key": stream_key(self.ecfg.seed if seed is None else seed),
         }
 
     # ------------------------------------------------------------ drafting ---
 
     def _draft_tree(self, stream, K, L1, L2):
-        """Draft a (K, L1, L2)-delayed tree.  Returns (tree, root_hidden)."""
-        rng = self.rng
-        dists, dcache, hid = self._draft_decode(stream["dcache"], stream["draft_delta"])
+        """Draft a (K, L1, L2)-delayed tree.  Returns (tree, root_hidden).
+
+        Every draft token is drawn on the device by the keyed draw both
+        engines share (``sampling.draw_keyed``, keyed by the stream's seed,
+        the committed length and the node index of ``delayed_layout``), from
+        the warped float32 q the tree records, so the engine's rng is spent
+        by verification alone.  Unlike the pool's fused draft programs, the
+        trunk reuses the ingest's one-token decode program: this engine is
+        the reference, not the serving path."""
+        t, p = self.sampling.temperature, self.sampling.top_p
+        warp = self._jit("draft_warp", partial(warp_logits, temperature=t, top_p=p))
+        draw = self._jit("draft_draw", draw_keyed)
+        T = len(stream["draft_delta"])
+        toks = jnp.asarray(np.asarray(stream["draft_delta"], np.int32)[None])
+        logits, dcache, ex = self._draft_fn(T)(self.dp, tokens=toks, cache=stream["dcache"])
+        self.counters["draft_calls"] += 1
+        self.counters["draft_tokens"] += T
         # dcache is now committed-consistent (delta tokens are committed) —
         # persist it immediately; trunk/branch drafting below works on local
         # functional values that are simply discarded (this also keeps
         # recurrent draft states exact, which a length rollback cannot).
         stream["dcache"] = dcache
-        q0 = dists[-1]
-        h_cur_q = hid[-1]
-        tokens, parent, depth, pid, qs = [-1], [-1], [0], [0], [q0]
-        node = 0
+        q = warp(logits[:, -1])
+        key = stream["key"][None]
+        C = len(stream["committed"])
+        qs, tokens = [q], [np.asarray([-1], np.int32)]
         # trunk: sequential single-token drafting
-        for _ in range(L1):
-            t = draw_token(rng, qs[node])
-            d1, dcache, _ = self._draft_decode(dcache, [t])
-            tokens.append(t)
-            parent.append(node)
-            depth.append(depth[node] + 1)
-            pid.append(0)
-            qs.append(d1[0])
-            node = len(tokens) - 1
-        branch_node = node
-        # branches: fork the draft cache K ways and roll L2 batched steps
+        for i in range(L1):
+            tok = draw(q, key, np.asarray([C], np.int32), np.asarray([i + 1], np.int32))
+            logits, dcache, _ = self._draft_fn(1)(self.dp, tokens=tok[:, None], cache=dcache)
+            q = warp(logits[:, -1])
+            qs.append(q)
+            tokens.append(tok)
+            self.counters["draft_calls"] += 1
+            self.counters["draft_tokens"] += 1
+        # branches: fork the draft cache K ways and roll L2 batched steps,
+        # branch k's next token drawn from its newest node's q
         if K > 0 and L2 > 0:
             fork = fork_cache(self.dc, dcache, K)
-            # per-branch trackers
-            cur_q = np.stack([qs[branch_node]] * K)
-            branch_nodes = [branch_node] * K
+            q = jnp.repeat(q, K, axis=0)
+            branch = self._jit("draft_branch", partial(forward, cfg=self.dc, mode="decode"))
             for j in range(L2):
-                ts = [draw_token(rng, cur_q[k]) for k in range(K)]
-                fn = self._jit("draft_branch", partial(forward, cfg=self.dc, mode="decode"))
-                logits, fork, _ = fn(
-                    self.dp, tokens=jnp.asarray(np.asarray(ts, np.int32)[:, None]), cache=fork
-                )
+                node = np.arange(1 + L1 + j * K, 1 + L1 + (j + 1) * K, dtype=np.int32)
+                tok = draw(q, np.repeat(key, K, axis=0), np.full(K, C, np.int32), node)
+                logits, fork, _ = branch(self.dp, tokens=tok[:, None], cache=fork)
+                q = warp(logits[:, -1])
+                qs.append(q)
+                tokens.append(tok)
                 self.counters["draft_calls"] += 1
                 self.counters["draft_tokens"] += K
-                dists_b = np.asarray(self._warp(logits[:, 0]))
-                # branch k's next token is drawn from its newest node's q
-                cur_q = dists_b
-                for k in range(K):
-                    tokens.append(ts[k])
-                    parent.append(branch_nodes[k])
-                    depth.append(depth[branch_nodes[k]] + 1)
-                    pid.append(k)
-                    qs.append(dists_b[k])
-                    branch_nodes[k] = len(tokens) - 1
+        parent, depth, pid = delayed_layout(K, L1, L2)
         tree = DraftTree(
-            tokens=np.asarray(tokens, np.int64),
-            parent=np.asarray(parent, np.int64),
-            depth=np.asarray(depth, np.int64),
-            q=np.stack(qs),
-            path_id=np.asarray(pid, np.int64),
+            tokens=np.concatenate([np.asarray(t) for t in tokens]).astype(np.int64),
+            parent=parent,
+            depth=depth,
+            q=np.concatenate([np.asarray(x) for x in qs]),
+            path_id=pid,
         )
-        return tree, h_cur_q
+        return tree, np.asarray(ex["hidden"][0, -1])
 
     def _rollback_len(self, cache, new_len, cfg):
         cache = dict(cache)

@@ -30,6 +30,7 @@ import numpy as np
 from repro.kernels.ops import interpret_mode, pool_commit_kv
 from repro.models.cache import merge_streams, paged_phys_slots
 from repro.models.transformer import forward
+from repro.sampling import draw_keyed, warp_logits
 
 
 def next_pow2(n: int) -> int:
@@ -118,32 +119,58 @@ def make_prefill_step(cfg):
 
 def make_pool_decode_step(cfg):
     """(params, pool_cache, tokens (B, Tpad), lens (B,)) ->
-    (logits, cache, hidden).
+    (logits (B, V), cache, hidden (B, d)) at each row's last position.
 
     Padded decode over a per-stream pool: row b's tokens beyond lens[b] are
     written but invalidated (pos = -1), so heterogeneous per-stream deltas
-    advance in one call.  Attention-family archs only (recurrent state
-    cannot be length-masked — use make_pool_locked_step)."""
+    advance in one call, and only each row's position ``lens - 1`` (the
+    draft ingest's new root) leaves the program.  Attention-family archs
+    only (recurrent state cannot be length-masked — drafting freezes rows
+    with merge_streams, see make_draft_draw_step)."""
 
     def step(params, cache, tokens, lens):
         logits, new_cache, ex = forward(params, cfg, tokens, mode="decode", cache=cache, lens=lens)
-        return logits, new_cache, ex["hidden"]
+        at = jnp.maximum(lens - 1, 0)[:, None, None]
+        last = jnp.take_along_axis(logits, at, axis=1)[:, 0]
+        hidden = jnp.take_along_axis(ex["hidden"], at, axis=1)[:, 0]
+        return last, new_cache, hidden
 
     return step
 
 
-def make_pool_locked_step(cfg):
-    """(params, pool_cache, tokens (B, 1), keep (B,)) -> (logits, cache).
+def make_draft_draw_step(cfg, temperature: float, top_p: float):
+    """(params, cache, q (R, V), key (R, 2), C (R,), node (R,), keep)
+    -> (tok (R,), q (R, V), cache).
 
-    One lockstep token per stream; rows with keep=False are frozen at their
-    exact prior state (merge_streams), which is the recurrent-safe padding
-    primitive."""
+    One drafting level of delayed-tree expansion, all on the device: draw
+    each row's token for tree node ``node`` from its warped q
+    (``sampling.draw_keyed``), advance the draft over it and warp the new
+    logits into the next q.  The q a token is drawn from is the same float32
+    array the verifier later reads, so sampler and verifier cannot drift.
+    ``keep`` (R,) bool freezes rows at their prior cache state and q
+    (merge_streams — the recurrent-safe lockstep used by the pool's trunk);
+    ``keep=None`` advances every row (branch rows, the single-stream
+    engine)."""
 
-    def step(params, cache, tokens, keep):
-        logits, new_cache, _ = forward(params, cfg, tokens, mode="decode", cache=cache)
-        return logits, merge_streams(new_cache, cache, keep)
+    def step(params, cache, q, key, C, node, keep=None):
+        tok = draw_keyed(q, key, C, node)
+        logits, new_cache, _ = forward(params, cfg, tok[:, None], mode="decode", cache=cache)
+        q_new = warp_logits(logits[:, 0], temperature, top_p)
+        if keep is not None:
+            new_cache = merge_streams(new_cache, cache, keep)
+            q_new = jnp.where(keep[:, None], q_new, q)
+        return tok, q_new, new_cache
 
     return step
+
+
+def take_rows(parts, idx):
+    """Rows ``idx`` of the concatenation of ``parts`` along axis 0, where
+    index ``sum(len(p) for p in parts)`` reads a zero row: how drafting's
+    per-level device outputs are gathered into tree-node order (and padding
+    nodes into zeros) without a host round trip."""
+    zero = jnp.zeros((1,) + parts[0].shape[1:], parts[0].dtype)
+    return jnp.concatenate([*parts, zero])[idx]
 
 
 def device_ancestor_mask(parents: jax.Array) -> jax.Array:

@@ -40,8 +40,8 @@ def collect_traces(
     """Returns arrays: h_prev_p, h_prev_q, h_cur_q, scalars, eff, time."""
     rng = np.random.default_rng(seed)
     rows = {k: [] for k in ["h_prev_p", "h_prev_q", "h_cur_q", "scalars", "eff", "time"]}
-    for prompt in prompts:
-        stream = engine.new_stream(list(prompt))
+    for i, prompt in enumerate(prompts):
+        stream = engine.new_stream(list(prompt), seed=seed + i)
         produced = 0
         since_root = stride  # take the first root immediately
         while produced < tokens_per_prompt:

@@ -73,7 +73,7 @@ def test_engine_first_token_distribution(models, verifier):
                             SamplingParams(temp, topp))
     for seed in range(n):
         eng.rng = np.random.default_rng(seed)
-        stream = eng.new_stream(list(prompt))
+        stream = eng.new_stream(list(prompt), seed=seed)
         toks = eng.step(stream)
         counts[toks[0]] += 1
     freq = counts / n

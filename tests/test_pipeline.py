@@ -14,8 +14,11 @@ docs/serving.md "Pipelined stepping":
     step i's verify phase (finish_step) completes (call-order probe, same
     style as test_commit_fused.py's one-commit-per-step assertion);
   * stall-and-drain: iterations that retire a stream never pipeline ahead,
-    and a begun step can be aborted (rng + draft pool + speculative target
-    writes rewound) without perturbing the token stream.
+    and a begun step can be aborted (draft pool + speculative target
+    writes rewound) without perturbing the token stream: begun again, it
+    drafts the same tokens from the same keys;
+  * drafting reads nothing back: ``draft_syncs`` stays 0 on the tree
+    strategy, for the padded and the ragged layout.
 """
 import jax
 import pytest
@@ -251,10 +254,9 @@ def test_stalled_iterations_do_not_run_ahead(dense_models):
 
 
 def test_abort_step_rewinds_exactly(dense_models):
-    """A begun step can be abandoned: rng snapshots restore the consumed
-    draws, the draft pool rolls back to its double-buffered frame, and the
-    target rows' speculative writes are invalidated — a subsequent run
-    emits exactly the untouched token stream."""
+    """A begun step can be abandoned: the draft pool's ingest writes are
+    erased, and the target rows' speculative writes are invalidated — a
+    subsequent run emits exactly the untouched token stream."""
     tc, tp, dc, dp = dense_models
     ecfg = EngineConfig(verifier="specinfer", K=2, L1=1, L2=1, max_cache=128)
     want = BatchedSpeculativeEngine(tc, tp, dc, dp, ecfg, n_slots=4) \
@@ -268,6 +270,42 @@ def test_abort_step_rewinds_exactly(dense_models):
     rids = sorted(st["rid"] for st in eng.streams.values())
     outs = eng.run()
     assert [outs[r]["tokens"] for r in rids] == want
+
+
+def test_rebegun_step_drafts_identical_tokens(dense_models):
+    """Draft draws are keyed by (seed, committed length, node), not drawn
+    from a consumed rng: a step aborted and begun again drafts bit-identical
+    tokens and node distributions."""
+    import numpy as np
+
+    tc, tp, dc, dp = dense_models
+    ecfg = EngineConfig(verifier="specinfer", K=2, L1=1, L2=1, max_cache=128)
+    eng = BatchedSpeculativeEngine(tc, tp, dc, dp, ecfg, n_slots=4, pipeline=True)
+    for p, sd in zip(PROMPTS, SEEDS):
+        eng.submit(p, max_new=12, seed=sd)
+    eng.step()  # leaves step 2 begun ahead, ingesting step 1's tokens
+    first = eng._pending_next
+    drafted = [np.asarray(first.tok_dev), np.asarray(first.q_dev)]
+    eng.abort_pipeline()
+    again = eng.begin_step()
+    np.testing.assert_array_equal(np.asarray(again.tok_dev), drafted[0])
+    np.testing.assert_array_equal(np.asarray(again.q_dev), drafted[1])
+
+
+@pytest.mark.parametrize("ragged", [False, "always"], ids=["padded", "ragged"])
+def test_tree_strategy_drafts_without_host_reads(dense_models, ragged):
+    """On the tree strategy no step blocks on the device between its draft
+    ingest and its tree dispatch: ``draft_syncs`` stays 0 while the steps'
+    drafts are still read back (once, in verify_step)."""
+    tc, tp, dc, dp = dense_models
+    ecfg = EngineConfig(verifier="specinfer", K=2, L1=2, L2=2, max_cache=128)
+    eng = BatchedSpeculativeEngine(tc, tp, dc, dp, ecfg, n_slots=4, pipeline=True,
+                                   ragged=ragged)
+    eng.generate_batch(PROMPTS, max_new=12, seeds=SEEDS)
+    c = eng.counters
+    assert c["steps_begun"] >= 4 and c["readback_bytes"] > 0
+    assert c["tree_calls_ragged" if ragged else "tree_calls_padded"] == c["target_calls"]
+    assert c["draft_syncs"] == 0
 
 
 def test_drain_pipeline_finishes_pending(dense_models):
@@ -292,7 +330,7 @@ def test_drain_pipeline_finishes_pending(dense_models):
 
 def test_submit_mid_pipeline_admits_like_sync(dense_models):
     """A submit() landing while a step is begun-ahead must not slip its
-    admission by one iteration: the pending step is aborted (rng + pools
+    admission by one iteration: the pending step is aborted (pools
     rewound) so the request joins at exactly the boundary the synchronous
     engine would, and every stream's tokens match the same call trace with
     pipeline=False."""

@@ -309,10 +309,11 @@ def test_admission_routes_around_exhausted_shard(dense_models):
 
 def test_multi_shard_abort_rewinds_all(dense_models):
     """``abort_pipeline`` with SEVERAL shards begun-ahead must rewind every
-    one of them: each shard restores its own rng snapshots and pool writes,
-    so the continued run still emits the synchronous sharded token stream.
-    (A partial rewind would replay one shard's randomness against another's
-    already-consumed state — the regression this pins down.)"""
+    one of them: each shard rewinds its own pool writes, so the continued
+    run still emits the synchronous sharded token stream.  (A partial
+    rewind would leave a shard's dispatched step standing through the
+    out-of-band change the rewind was for — the regression this pins
+    down.)"""
     tc, tp, dc, dp = dense_models
     ecfg = EngineConfig(verifier="specinfer", K=2, L1=1, L2=1, max_cache=128)
     base = ShardedBatchedSpeculativeEngine(tc, tp, dc, dp, ecfg, n_slots=4,

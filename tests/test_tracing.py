@@ -2,8 +2,8 @@
 (serving/tracing.py, docs/serving.md "Spans and counters").
 
   * a traced run writes the ``serve:`` phase spans and ``serve:wait.*``
-    read spans, with the pipelined engine's begun-ahead ``serve:begin``
-    nested inside ``serve:retire``;
+    read spans, none inside drafting, with the pipelined engine's
+    begun-ahead ``serve:begin`` nested inside ``serve:retire``;
   * span metadata (the request's ``rid``) leaves the event name clean;
   * a closed loop (a new request on every ``done``) makes the pipelined
     engine rewind begun steps, and ``steps_begun == finished steps +
@@ -80,14 +80,21 @@ def test_traced_run_writes_phase_and_wait_spans(dense_models, tmp_path, pipeline
     for phase in ("step", "begin", "admit", "prefill", "ingest", "draft", "dispatch",
                   "verify", "commit", "retire"):
         assert f"serve:{phase}" in names, phase
-    for what in ("prefill", "ingest", "draft", "tree", "hidden"):
+    for what in ("prefill", "draft", "tree", "hidden"):
         assert f"serve:wait.{what}" in names, what
-    # every wait sits inside a served step; phases nest as the call stack
+    # every wait sits inside a served step; phases nest as the call stack;
+    # drafting (ingest and tree levels, on the device) waits on nothing:
+    # its outputs are read once, in verify
     steps = [e for e in events if e[0] == "serve:step"]
     assert len(steps) == eng._step_no
+    drafting = [e for e in events if e[0] in ("serve:ingest", "serve:draft")]
+    verifies = [e for e in events if e[0] == "serve:verify"]
     for e in events:
         if e[0].startswith("serve:wait."):
             assert any(_within(e, s) for s in steps), e
+            assert not any(_within(e, d) for d in drafting), e
+            if e[0] == "serve:wait.draft":
+                assert any(_within(e, v) for v in verifies), e
     begins = [e for e in events if e[0] == "serve:begin"]
     retires = [e for e in events if e[0] == "serve:retire"]
     ahead = [b for b in begins if any(_within(b, r) for r in retires)]
