@@ -75,7 +75,7 @@ def _attention(q, k, v, G):
 
 @partial(jax.jit, static_argnames=("m", "quant"))
 def logits(params, tokens, *, m: tuple, quant: bool = False):
-    """(T, V) float32 logits of one sequence; ``m`` is ``weights.dims_of``
+    """(T, V) float32 logits of one sequence; ``m`` is ``families/dense.dims``
     as a sorted tuple of items."""
     m = dict(m)
     H, Hkv, hd, eps = m["H"], m["Hkv"], m["hd"], m["eps"]

@@ -1,9 +1,10 @@
-"""Faults planted under the timed path, each a way a one-chip serving cell
-can go wrong.  ``bench/tests/test_control.py`` drives a whole run with each
-and expects ``correct`` to come out false; ``bench/run.py --fault <name>``
+"""Faults planted under the timed path, each a way a serving cell can go
+wrong.  ``bench/tests/test_control.py`` drives a whole run with each and
+expects ``correct`` to come out false; ``bench/run.py --fault <name>``
 plants one on the chip to read the number it has to fail.
 
-Each takes the engine after it is built and patches it in place."""
+Each takes a pool engine after it is built and patches it in place; the
+harness plants it in every shard's engine of a sharded one."""
 from __future__ import annotations
 
 import jax.numpy as jnp

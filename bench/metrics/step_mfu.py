@@ -1,7 +1,8 @@
 """Device: the whole step's share of the chip's bf16 peak.  Operations of
 the target and draft forwards over the real tokens the window processed
 (tree nodes, draft ingest and drafting tokens, prefill tokens; no padding),
-counted from shapes by ``bench/flops.py``, over window x chips x peak."""
+counted from shapes by the configuration's family
+(``bench/families/<family>.py``), over window x chips x peak."""
 
 
 def read(rec):

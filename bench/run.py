@@ -6,15 +6,19 @@ traffic on the chips of this machine.
 
 The cell (``BENCHMARK.json``'s ``workloads`` entry) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``); per-layer metrics are readers in
-``bench/metrics/<metric>.py``.  A run:
+(``bench/traffic/<traffic>.json``); the configuration names its family
+(``bench/families/<family>.py``: its sizes, weights, work and, where the
+harness's own does not cover its programs, warm-up); per-layer metrics are
+readers in ``bench/metrics/<metric>.py``.  A run:
 
 1. set-up (``setup_s``, from process start to the first timed step): random
    bf16 weights made on the device in one compiled call from the
    configuration's ``weight_seed`` (every seed serves the same model), the
    engine built as ``launch/serve.build_engine`` builds it but with the
-   traffic file's settings, then a warm-up of every program shape the
-   cell's traffic can reach, then a pre-window of the traffic itself;
+   traffic file's settings (a cell on more than one chip: the sharded
+   engine, one pool and one chip a shard, behind its router), then a
+   warm-up of every program shape the cell's traffic can reach, then a
+   pre-window of the traffic itself;
 2. the window: ``--seconds`` of closed-loop serving, one client per pool
    row, each submitting its next request as soon as its last one ends;
    with ``--trace 1`` under the profiler, with the harness's spans;
@@ -64,10 +68,9 @@ sys.path.insert(0, str(BENCH))
 
 import numpy as np  # noqa: E402
 
-import flops  # noqa: E402
 from faults import FAULTS  # noqa: E402
 from traffic import Clients  # noqa: E402
-from weights import dims_of, make_weights  # noqa: E402
+from weights import make_weights  # noqa: E402
 
 WORK_DIR = ROOT / ".bench"
 
@@ -84,8 +87,8 @@ def load_module(path: Path):
 
 
 def load_cell(name: str) -> dict:
-    """The cell's entry, configuration, traffic and metric list, found by
-    name from ``BENCHMARK.json``."""
+    """The cell's entry, configuration, family, traffic and metric list,
+    found by name from ``BENCHMARK.json``."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -94,8 +97,9 @@ def load_cell(name: str) -> dict:
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     per_layer = [m for m in bench["per_layer"] if name in m.get("workloads", [name])]
     end_to_end = [m for m in bench["end_to_end"] if name in m.get("workloads", [name])]
-    return {"cell": cell,
-            "config": json.loads((ROOT / conf["file"]).read_text()),
+    config = json.loads((ROOT / conf["file"]).read_text())
+    return {"cell": cell, "config": config,
+            "family": load_module(BENCH / "families" / f"{config['family']}.py"),
             "traffic": json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
             "limits": json.loads((BENCH / "limits" / f"{name}.json").read_text()),
             "per_layer": per_layer, "end_to_end": end_to_end}
@@ -154,30 +158,35 @@ class CompileCounter:
 
 # ------------------------------------------------------------------ engine --
 
+def engines(eng) -> list:
+    """The pool engines a (possibly sharded) engine drives, in shard order."""
+    return getattr(eng, "shards", [eng])
+
+
 def build(spec: dict, seed: int):
     """Program configs checked against the configuration file, seeded
-    weights, and the engine with the traffic file's settings."""
+    weights, and the engine with the traffic file's settings: one pool, or
+    on a cell of more than one chip a pool and a chip a shard."""
     import jax
 
     sys.path.insert(0, str(ROOT / "src"))
     from repro.configs import get_config, get_smoke
     from repro.launch.serve import make_draft_cfg
     from repro.models.transformer import init_params
-    from repro.serving.batch_engine import BatchedSpeculativeEngine
+    from repro.serving.batch_engine import (
+        BatchedSpeculativeEngine, ShardedBatchedSpeculativeEngine)
     from repro.serving.engine import EngineConfig, SamplingParams
 
-    conf, eng_s = spec["config"], spec["traffic"]["engine"]
+    conf, eng_s, fam = spec["config"], spec["traffic"]["engine"], spec["family"]
     base = get_smoke(conf["arch"]) if conf.get("smoke") else get_config(conf["arch"])
     cfg = base.replace(attention_impl=eng_s["attention_impl"], **conf.get("program_replace", {}))
     dcfg = make_draft_cfg(cfg)
-    mt, md = dims_of(conf), dims_of(conf["draft"])
+    mt, md = fam.dims(conf), fam.dims(conf["draft"])
     for c, m, what in ((cfg, mt, "target"), (dcfg, md, "draft")):
-        have = {"d": c.d_model, "L": c.n_layers, "H": c.n_heads, "Hkv": c.n_kv_heads,
-                "hd": c.hd, "f": c.d_ff, "V": c.vocab, "tied": c.tie_embeddings,
-                "theta": c.rope_theta, "eps": c.norm_eps}
+        have = fam.program_dims(c)
         if have != m:
             raise Refused(f"{what} of the program {have} differs from the configuration file {m}")
-    tp, dp = make_weights(conf["weight_seed"], mt, md)
+    tp, dp = make_weights(fam, conf["weight_seed"], mt, md)
     for p, c in ((tp, cfg), (dp, dcfg)):
         want = jax.eval_shape(lambda k, c=c: init_params(c, k), jax.random.PRNGKey(0))
         got = jax.tree.map(lambda a: (a.shape, a.dtype), p)
@@ -186,10 +195,13 @@ def build(spec: dict, seed: int):
             raise Refused(f"weights of {c.name} do not have the program's layout")
     ecfg = EngineConfig(verifier=eng_s["verifier"], K=eng_s["K"], L1=eng_s["L1"],
                         L2=eng_s["L2"], max_cache=eng_s["max_cache"], seed=seed)
-    eng = BatchedSpeculativeEngine(
-        cfg, tp, dcfg, dp, ecfg, SamplingParams(eng_s["temperature"], eng_s["top_p"]),
-        n_slots=eng_s["streams"], paged=True, block_size=eng_s["block_size"],
-        pipeline=eng_s["pipeline"], ragged=eng_s["ragged"])
+    args = (cfg, tp, dcfg, dp, ecfg, SamplingParams(eng_s["temperature"], eng_s["top_p"]))
+    kw = dict(n_slots=eng_s["streams"], paged=True, block_size=eng_s["block_size"],
+              pipeline=eng_s["pipeline"], ragged=eng_s["ragged"])
+    if spec["cell"]["chips"] > 1:
+        eng = ShardedBatchedSpeculativeEngine(*args, data_shards=spec["cell"]["chips"], **kw)
+    else:
+        eng = BatchedSpeculativeEngine(*args, **kw)
     return eng, mt, md
 
 
@@ -197,18 +209,49 @@ class Recorder:
     """What the harness reads from the engine while it runs: per request the
     token the target tree pass and the draft put first at every served
     position, and, in traced runs, the phases as ``bench:`` spans plus the
-    real work each step did (for ``step_mfu``)."""
+    real work each step did (for ``step_mfu``, counted by the family).  A
+    sharded engine is read shard by shard, its requests keyed by the id the
+    router's ``submit`` returns, and a fault is planted in every shard."""
 
     SPANS = {"begin_step": "begin", "_admit": "admit", "_ingest_deltas": "draft",
              "_draft_trees": "draft", "_target_tree_dispatch": "dispatch",
              "_target_tree_dispatch_ragged": "dispatch", "verify_step": "verify",
              "commit_step": "commit", "retire_step": "retire"}
 
-    def __init__(self, eng, mt: dict, md: dict, spans: bool, fault=None):
-        self.mt, self.md = mt, md
+    def __init__(self, eng, family, mt: dict, md: dict, spans: bool, fault=None):
+        self.family, self.mt, self.md = family, mt, md
         self.first: dict[int, list] = {}
+        self.ids: dict[tuple, int] = {}  # (shard, the shard's id) -> the router's id
         self.work_on = False
         self.model_ops = 0
+        shards = engines(eng)
+        for si, sh in enumerate(shards):
+            self._read(sh, si, spans)
+            if fault is not None:
+                fault(sh)
+        if len(shards) > 1:
+            self._key_by_router(eng)
+
+    def _key_by_router(self, eng) -> None:
+        """Map each shard's request id to the router's: the router's
+        ``submit`` calls one shard's ``submit`` and returns its own id."""
+        last = []
+        for si, sh in enumerate(eng.shards):
+            def submit(*a, _submit=sh.submit, si=si, **k):
+                last[:] = [(si, _submit(*a, **k))]
+                return last[0][1]
+
+            sh.submit = submit
+        route = eng.submit
+
+        def routed(*a, **k):
+            rid = route(*a, **k)
+            self.ids[last[0]] = rid
+            return rid
+
+        eng.submit = routed
+
+    def _read(self, eng, si: int, spans: bool) -> None:
         from repro.serving.engine import SpeculativeEngine
 
         accepted_nodes = SpeculativeEngine._accepted_nodes
@@ -217,7 +260,8 @@ class Recorder:
         def advance(slot, tree, accepted, corr, h_q, node_path=None):
             st = eng.streams[slot]
             path = node_path if node_path is not None else accepted_nodes(tree, accepted)
-            self.first.setdefault(st["rid"], []).extend(
+            rid = self.ids.get((si, st["rid"]), st["rid"])
+            self.first.setdefault(rid, []).extend(
                 (int(np.argmax(tree.p[n])), int(np.argmax(tree.q[n]))) for n in [0, *path])
             if self.work_on:
                 self._count_step(st, tree)
@@ -234,12 +278,10 @@ class Recorder:
             def prefill(cfg, params, ctx, name):
                 if self.work_on:
                     m = self.mt if name == "tgt" else self.md
-                    self.model_ops += flops.prefill_flops(m, len(ctx))
+                    self.model_ops += self.family.prefill_flops(m, len(ctx))
                 return pre(cfg, params, ctx, name)
 
             eng._prefill_row = prefill
-        if fault is not None:
-            fault(eng)
 
     @staticmethod
     def _span(fn, name, jax):
@@ -252,22 +294,24 @@ class Recorder:
         """Real work of one stream's verified step: its tree pass (every
         node against the committed prefix and its ancestors), its draft
         ingest, and the draft tokens that built the tree."""
+        flops = self.family.forward_flops
         prefix = len(st["committed"]) - 1
         depths = [int(d) for d in tree.depth]
         ctx = sum(prefix + d + 1 for d in depths)
-        self.model_ops += flops.forward_flops(self.mt, len(depths), ctx)
+        self.model_ops += flops(self.mt, len(depths), ctx)
         n_ing = len(st["draft_delta"])
         n_draft = len(depths) - 1
-        self.model_ops += flops.forward_flops(self.md, n_ing + n_draft,
-                                             (n_ing + n_draft) * (prefix + 1))
+        self.model_ops += flops(self.md, n_ing + n_draft, (n_ing + n_draft) * (prefix + 1))
 
 
 def warm_up(eng, traffic: dict) -> None:
-    """Compile every program shape the cell's traffic can reach before the
-    window: each prefill bucket of target and draft, the draft ingest widths,
-    the padded and ragged tree passes, the fused commits, the probability
-    warps on each of their shapes, and the pipeline's rewind for every row
-    count.  Every call leaves the (still empty) pool as it found it."""
+    """The harness's warm-up of one pool engine on the tree strategy, for a
+    family that brings none.  Compile every program shape the cell's
+    traffic can reach before the window: each prefill bucket of target and
+    draft, the draft ingest widths, the padded and ragged tree passes, the
+    fused commits, the probability warps on each of their shapes, and the
+    pipeline's rewind for every row count.  Every call leaves the (still
+    empty) pool as it found it."""
     import jax.numpy as jnp
 
     from repro.serving.serve_step import (
@@ -319,6 +363,19 @@ def warm_up(eng, traffic: dict) -> None:
     import jax
 
     jax.block_until_ready((eng.tpool.cache, eng.dpool.cache))
+
+
+def warm_all(eng, spec: dict) -> None:
+    """The family's warm-up, or the harness's, on every shard's engine in
+    turn."""
+    warm = getattr(spec["family"], "warm_up", warm_up)
+    for sh in engines(eng):
+        warm(sh, spec["traffic"])
+
+
+def pools(eng) -> list:
+    """Every shard's target pool, for a wait on the device."""
+    return [sh.tpool.cache for sh in engines(eng)]
 
 
 # ------------------------------------------------------------------ window --
@@ -376,14 +433,15 @@ class Loop:
         while any(not m["times"] for m in waiting):
             self.step()
 
-    def metrics(self) -> dict:
+    def metrics(self, chips: int) -> dict:
+        """The end-to-end metrics; output tokens per second per chip."""
         t0, t1 = self.window
         tokens = sum(k for m in self.meta.values()
                      for t, k in zip(m["times"], m["counted"]) if t0 < t <= t1)
         gaps = [b - a for m in self.meta.values()
                 for a, b in zip(m["times"], m["times"][1:]) if t0 <= a and b <= t1]
         ttft = [m["times"][0] - m["t_submit"] for m in self.meta.values() if m["in_window"]]
-        return {"out_tok_s": tokens / (t1 - t0),
+        return {"out_tok_s": tokens / (t1 - t0) / chips,
                 "itl_p95_ms": 1e3 * statistics.quantiles(gaps, n=20)[-1],
                 "ttft_p50_ms": 1e3 * statistics.median(ttft),
                 "_tokens": tokens, "_gaps": len(gaps), "_ttft_n": len(ttft)}
@@ -415,10 +473,10 @@ def reference_readings(spec: dict, seqs: list, control: bool) -> dict:
     import jax
     import jax.numpy as jnp
 
-    conf = spec["config"]
+    conf, fam = spec["config"], spec["family"]
     ref = load_module(BENCH / "configs" / f"{conf['reference']}.py")
-    mt, md = dims_of(conf), dims_of(conf["draft"])
-    tp, dp = make_weights(conf["weight_seed"], mt, md)
+    mt, md = fam.dims(conf), fam.dims(conf["draft"])
+    tp, dp = make_weights(fam, conf["weight_seed"], mt, md)
     T = spec["traffic"]["check_len"]
     gaps = ["gap_target", "gap_draft"] + (["control_target", "control_draft"] if control else [])
     sums = {k: 0.0 for k in ("nll", "nll_mean", "nll_var", "llr", "llr_mean", "llr_var")}
@@ -507,15 +565,15 @@ def serve(spec: dict, seed: int, seconds: float, trace: bool, devs, peak: dict,
     compiles = CompileCounter()
     traffic = spec["traffic"]
     eng, mt, md = build(spec, seed)
-    rec = Recorder(eng, mt, md, spans=trace, fault=fault)
-    warm_up(eng, traffic)
+    rec = Recorder(eng, spec["family"], mt, md, spans=trace, fault=fault)
+    warm_all(eng, spec)
     clients = Clients(traffic, mt["V"], seed)
     loop = Loop(eng, clients)
     for c in range(clients.n):
         loop.submit(c)
     for _ in range(traffic["prewindow_steps"]):
         loop.step()
-    jax.block_until_ready(eng.tpool.cache)
+    jax.block_until_ready(pools(eng))
     # Exempt what set-up made (modules, compiled programs, the engine) from
     # the collector's full passes, as a long-running server does once it is
     # warm: such a pass rescans all of it, about 0.1-0.4 s each, a few times
@@ -539,16 +597,17 @@ def serve(spec: dict, seed: int, seconds: float, trace: bool, devs, peak: dict,
         loop.on_step = lambda: occupancy.append(len(eng.streams) / eng.n_slots)
         with jax.profiler.TraceAnnotation("bench:window"):
             loop.run_window(seconds)
-            jax.block_until_ready(eng.tpool.cache)
+            jax.block_until_ready(pools(eng))
         rec.work_on = False
         jax.profiler.stop_trace()
     else:
         loop.run_window(seconds)
     c_window = compiles.compiles - c_setup
-    e2e = loop.metrics()
+    e2e = loop.metrics(spec["cell"]["chips"])
     counters = {k: v - counters0[k] for k, v in eng.counters.items()}
     stats = [d.memory_stats() or {} for d in devs[:spec["cell"]["chips"]]]
     mem_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
+    log(f"peak bytes in use by chip: {[s.get('peak_bytes_in_use') for s in stats]}")
     log(f"window: {loop.window[1] - loop.window[0]:.3f} s, {e2e['_tokens']} tokens, "
         f"{e2e['_gaps']} token gaps, {e2e['_ttft_n']} requests submitted, "
         f"{counters['blocks']} blocks, {c_window} compiles inside the window")
@@ -557,7 +616,7 @@ def serve(spec: dict, seed: int, seconds: float, trace: bool, devs, peak: dict,
     result: dict = {"correct": False, "attempted": len(window_reqs),
                     "failed": sum(1 for r in window_reqs if r in eng.finished
                                   and eng.finished[r]["reason"] != "length"),
-                    "metrics": {}}
+                    "metrics": {}, "compiles": {"setup": c_setup, "window": c_window}}
     if trace:
         loop_rec = {"counters": counters, "occupancy": occupancy, "steps": len(occupancy)}
         lrec, device_extra, breakdown = reduce_trace(trace_dir, rec, loop_rec, peak,

@@ -92,8 +92,8 @@ def report(spec: dict, seed: int, seconds: float, *, allow_cpu: bool = False,
         found.update(program_readings(pt.load(str(trace_dir)), rec))
         return rec, device, breakdown
 
-    def metrics(loop):
-        e2e = metrics0(loop)
+    def metrics(loop, chips):
+        e2e = metrics0(loop, chips)
         found["end_to_end"] = e2e
         return e2e
 

@@ -1,6 +1,5 @@
-"""The benchmark's yardstick on the CPU: operation counts against hand
-counts at granite-3-2b's widths, and the trace reduction on a small
-synthetic trace."""
+"""The benchmark's yardstick on the CPU: the trace reduction and the
+per-layer readers on a small synthetic trace."""
 from __future__ import annotations
 
 import importlib.util
@@ -8,9 +7,8 @@ import json
 
 import pytest
 
-from smoke import BENCH
+from smoke import BENCH, family
 
-import flops
 import reduce_trace as rt
 
 G32B = json.loads((BENCH / "configs" / "granite-3-2b.json").read_text())
@@ -18,9 +16,7 @@ G32B = json.loads((BENCH / "configs" / "granite-3-2b.json").read_text())
 
 @pytest.fixture
 def m():
-    from weights import dims_of
-
-    return dims_of(G32B)
+    return family(BENCH / "families" / "dense.py").dims(G32B)
 
 
 def reader(name):
@@ -28,22 +24,6 @@ def reader(name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
-
-
-def test_matmul_params_by_hand(m):
-    # per layer: q 2048x2048, k and v 2048x512 each, o 2048x2048, MLP 3 x 2048x8192
-    per_layer = 2048 * 2048 + 2 * 2048 * 512 + 2048 * 2048 + 3 * 2048 * 8192
-    assert per_layer == 60817408
-    assert flops.matmul_params(m, head=False) == 40 * per_layer
-    assert flops.matmul_params(m, head=True) == 40 * per_layer + 2048 * 49155
-
-
-def test_forward_and_prefill_flops_by_hand(m):
-    # one token at context 100: 2 x params + 4 x L x H x hd x 100
-    one = 2 * (40 * 60817408 + 2048 * 49155) + 4 * 40 * 32 * 64 * 100
-    assert flops.forward_flops(m, 1, 100) == one
-    # prefill of 3 tokens: contexts 1, 2, 3, no head
-    assert flops.prefill_flops(m, 3) == 2 * 40 * 60817408 * 3 + 4 * 40 * 32 * 64 * 6
 
 
 def test_busy_union_and_gaps():
